@@ -15,6 +15,7 @@ import argparse
 import json
 import sys
 import time
+from pathlib import Path
 
 from . import (bench_bandwidth, bench_cameras, bench_compute,
                bench_dataplane, bench_energy, bench_engine, bench_faults,
@@ -64,6 +65,8 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated benchmark name substrings")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable(Path(__file__).resolve().parents[1])
     t0 = time.time()
     print(f"# meta: {json.dumps(common.run_metadata(), default=float)}\n",
           flush=True)

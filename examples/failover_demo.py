@@ -4,6 +4,8 @@ LBCD's server-selection subproblem re-places its streams on the next epoch
 
     PYTHONPATH=src python examples/failover_demo.py
 """
+from pathlib import Path
+
 import numpy as np
 
 from repro.core import lbcd, profiles
@@ -36,4 +38,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable(Path(__file__).resolve().parents[1])
     main()

@@ -20,6 +20,7 @@
     PYTHONPATH=src python examples/fault_storm.py [--smoke] [--policies lbcd,min]
 """
 import argparse
+from pathlib import Path
 
 import numpy as np
 
@@ -85,6 +86,8 @@ def main(smoke: bool = False, policies: tuple = ("lbcd", "min")):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable(Path(__file__).resolve().parents[1])
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny dimensions for CI smoke runs")

@@ -8,6 +8,8 @@
 
     PYTHONPATH=src python examples/quickstart.py
 """
+from pathlib import Path
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -53,4 +55,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable(Path(__file__).resolve().parents[1])
     main()

@@ -38,6 +38,7 @@
         [--obs DIR]
 """
 import argparse
+from pathlib import Path
 
 import jax
 
@@ -109,6 +110,8 @@ def main(smoke: bool = False, dataplane: bool = False,
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable(Path(__file__).resolve().parents[1])
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny dimensions for CI smoke runs")

@@ -11,6 +11,7 @@ Two data planes:
     PYTHONPATH=src python examples/serve_e2e.py [--engine] [--epochs 6]
 """
 import argparse
+from pathlib import Path
 
 import numpy as np
 
@@ -59,4 +60,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable(Path(__file__).resolve().parents[1])
     main()

@@ -8,6 +8,7 @@ A crash mid-run resumes from the last atomic checkpoint:
 """
 import argparse
 import dataclasses
+from pathlib import Path
 
 from repro import configs
 from repro.launch.train import run
@@ -47,4 +48,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable(Path(__file__).resolve().parents[1])
     main()

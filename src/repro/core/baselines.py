@@ -127,8 +127,7 @@ def _baseline_scan(solver_backend: str, n: int):
     """
     spec = bcd.resolve_spec(solver_backend, n)
     return functools.partial(slot_solver.baseline_argmax,
-                             backend=spec.backend,
-                             block_n=spec.tile_n or 1024)
+                             backend=spec.backend)
 
 
 @functools.partial(jax.jit, static_argnames=("solver_backend",))

@@ -92,16 +92,18 @@ def parse_backend(solver_backend) -> SolverSpec:
 
 
 def resolve_spec(solver_backend, n_cameras: int,
-                 method: str = "waterfill") -> SolverSpec:
+                 method: str = "waterfill",
+                 masked: bool = False) -> SolverSpec:
     """Resolve a spec (or spec string) to concrete knobs for a fleet size.
 
     ``"auto"`` picks jnp below :data:`AUTO_PALLAS_MIN_CAMERAS`
     (lane-padding regime) and pallas at or above it, and — unless the
     spec pins ``tile=``— engages the tiled water-fill with
     :data:`DEFAULT_TILE_N` from :data:`AUTO_TILE_MIN_CAMERAS` cameras.
-    ``method="interior"`` is jnp-only so auto never selects pallas for
-    it. Explicit backends pass through unchanged (including the
-    pallas+interior error path in ``solve_slot``). ``tile=0`` resolves
+    ``method="interior"`` and churn-masked solves (``masked=True``; the
+    pallas kernels take no mask) are jnp-only, so auto never selects
+    pallas for them. Explicit backends pass through unchanged (including
+    the pallas+interior and pallas+mask error paths in ``solve_slot``). ``tile=0`` resolves
     to untiled, and so does any tile the whole fleet fits inside
     (``n_cameras <= tile_n``) — streaming a single tile would just be
     the whole-fleet kernel plus DMA overhead, and dropping the tile
@@ -111,7 +113,8 @@ def resolve_spec(solver_backend, n_cameras: int,
     spec = parse_backend(solver_backend)
     backend = spec.backend
     if backend == "auto":
-        if method != "waterfill" or n_cameras < AUTO_PALLAS_MIN_CAMERAS:
+        if (method != "waterfill" or masked
+                or n_cameras < AUTO_PALLAS_MIN_CAMERAS):
             backend = "jnp"
         else:
             backend = "pallas"
@@ -192,10 +195,10 @@ def solve_slot(acc, xi, size, eff, server_id, budgets_b, budgets_c, q, V,
       active: optional [N] fleet-churn mask (1 = live). Inactive cameras
         get exactly zero bandwidth/compute (their budget share
         redistributes to survivors) and are excluded from the drift-plus-
-        penalty means. The masked path runs on the jnp backend (the
-        pallas kernels take no mask — a masked solve silently forces
-        jnp); ``active=None`` traces the identical program as before the
-        parameter existed.
+        penalty means. The masked path runs on the jnp backend: the
+        pallas kernels take no mask, so ``"auto"`` resolves to jnp and an
+        explicit ``"pallas"`` raises ``ValueError``; ``active=None``
+        traces the identical program as before the parameter existed.
     """
     kwargs = dict(n_servers=n_servers, n_iters=n_iters, method=method,
                   solver_effort=solver_effort,
@@ -210,7 +213,8 @@ def solve_slot(acc, xi, size, eff, server_id, budgets_b, budgets_c, q, V,
         # (inside rollout scans / vmaps) bump a per-backend trace counter
         # instead (wall time inside a trace measures tracing, not the
         # solver).
-        spec = resolve_spec(solver_backend, acc.shape[0], method=method)
+        spec = resolve_spec(solver_backend, acc.shape[0], method=method,
+                            masked=active is not None)
         backend = (spec.backend if spec.tile_n is None
                    else f"{spec.backend}:tiled")
         operands = args if active is None else args + (active,)
@@ -234,14 +238,16 @@ def _solve_slot(acc, xi, size, eff, server_id, budgets_b, budgets_c, q, V,
                 solver_effort: Literal["fast", "seed"] = "fast",
                 solver_backend: str = "jnp",
                 interpret: bool | None = None, active=None):
-    spec = resolve_spec(solver_backend, acc.shape[0], method=method)
+    spec = resolve_spec(solver_backend, acc.shape[0], method=method,
+                        masked=active is not None)
     if active is not None:
         if method == "interior":
             raise ValueError("method='interior' does not support a fleet-"
                              "churn mask; use method='waterfill'")
-        # The pallas kernels take no churn mask — a masked solve runs on
-        # the jnp reference path regardless of the requested backend.
-        spec = SolverSpec("jnp", None, spec.fuse)
+        if spec.backend == "pallas":
+            raise ValueError("solver_backend='pallas' takes no fleet-churn "
+                             "mask; use 'auto' (resolves to jnp for masked "
+                             "solves) or 'jnp'")
     use_pallas = spec.backend == "pallas"
     if use_pallas and method != "waterfill":
         raise ValueError("solver_backend='pallas' fuses the water-filling "
@@ -267,8 +273,7 @@ def _solve_slot(acc, xi, size, eff, server_id, budgets_b, budgets_c, q, V,
         # sorted/padded into per-server rows the kernel programs own.
         layout = slot_solver.server_layout(server_id, n_servers)
         config = functools.partial(slot_solver.config_argmin,
-                                   backend="pallas", interpret=interpret,
-                                   block_n=spec.tile_n or 1024)
+                                   backend="pallas", interpret=interpret)
         # The fused pair kernel holds the whole fleet in one program; the
         # camera-tiled water-fills stream it in two (bandwidth, compute).
         if spec.fuse and spec.tile_n is None:

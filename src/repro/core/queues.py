@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental import enable_x64
 
 from .. import obs
 
@@ -453,7 +452,7 @@ def gi_g1_window(lam, mu, p, pol, *, seed: int = 0, t0: int = 0,
     recurrence as an ``[E*N]`` vector — single-pass like the numpy
     oracle's cumsums, but batched across the whole window. Short frame
     budgets (<= ``F32_MAX_FRAMES``) run in float32; longer horizons
-    switch to float64 (scoped ``enable_x64``) so multi-hour epochs keep
+    switch to float64 (scoped ``jax.enable_x64``) so multi-hour epochs keep
     sub-millisecond age resolution, matching the oracle. Returns host
     numpy: ``aopi``/``horizon``/``n_frames``/``n_completed``/
     ``n_accurate``, each ``[E, N]``.
@@ -475,7 +474,7 @@ def gi_g1_window(lam, mu, p, pol, *, seed: int = 0, t0: int = 0,
     obs.histogram("queues.batch_elems",
                   delay_model=delay_model).observe(e * n * n_frames)
     with obs.span("queues.gi_g1_window", delay_model=delay_model,
-                  epochs=e, streams=n, n_frames=n_frames), enable_x64():
+                  epochs=e, streams=n, n_frames=n_frames), jax.enable_x64(True):
         keys = jax.vmap(jax.random.fold_in, (None, 0))(
             jax.random.key(int(seed)), jnp.arange(t0, t0 + e))
         out = _window_sim(

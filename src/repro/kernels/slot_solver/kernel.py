@@ -4,13 +4,14 @@ Four kernels, all pure VPU work (no MXU):
 
   * ``config_argmin`` — Algorithm 1 line 3. The jnp backend materializes the
     ``[N, M, R, 2]`` FCFS/LCFSP score tensor in HBM once per BCD pass (and
-    again for every vmap lane of a grid/scenario sweep). Here the camera
-    axis is tiled across the grid and the model axis is a static on-chip
-    loop: each program holds one ``[block_n, R]`` score slice in VMEM,
-    folds it into a running per-camera ``(best_value, best_flat_index)``
-    pair, and writes only the three ``[N]`` index vectors back to HBM. Tie
-    breaking matches the reference's flat argmin exactly (first index in
-    (m, r, policy) order, strict-``<`` fold over models).
+    again for every vmap lane of a grid/scenario sweep). Here cameras sit
+    on the 128 lanes (``[rows, 128]`` tiles, one accuracy plane per
+    (m, r) config) and the grid streams blocks of rows; each program
+    loops over models on-chip, folds every (m, r, policy) score into a
+    running per-camera ``(best_value, best_flat_index)`` pair, and writes
+    only the three index rows back to HBM. Tie breaking matches the
+    reference's flat argmin exactly (first index in (m, r, policy) order,
+    strict-``<`` fold).
 
   * ``waterfill`` — Algorithm 1 lines 4/5. The grid program owns the whole
     fleet: cameras arrive stably sorted into contiguous per-server blocks
@@ -69,89 +70,112 @@ _EPS = 1e-12
 
 
 # ---------------------------------------------------------------------------
+# Camera-on-lanes layout shared by the config scans
+# ---------------------------------------------------------------------------
+
+LANES = 128      # TPU vreg lanes
+SUBLANES = 8     # TPU vreg sublanes (f32)
+
+
+def block_rows_for(n_rows: int, block_n: int) -> int:
+    """Grid block height (in 128-camera rows) for the config scans.
+
+    A block is either the whole (short) row axis or a multiple of the
+    8-sublane tile, so Mosaic accepts it at any fleet size; ``block_n``
+    is the requested cameras per grid step. The wrapper pads the row
+    axis to a multiple of the result."""
+    rows = max(SUBLANES, -(-int(block_n) // (LANES * SUBLANES)) * SUBLANES)
+    return n_rows if n_rows <= rows else rows
+
+
+def _scan_specs(n_in_vecs: int, n_cfg: int, n_out: int, block_rows: int):
+    """BlockSpecs of a config scan: 2-D SMEM scalars/tables (2-D so a
+    vmapped call's batch block keeps their full trailing dims), ``n_in_vecs``
+    per-camera ``[rows, 128]`` vectors, the ``[n_cfg, rows, 128]``
+    accuracy planes, and one stacked ``[n_out, rows, 128]`` output."""
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
+    return ([smem] * 3 + [vec] * n_in_vecs +
+            [pl.BlockSpec((n_cfg, block_rows, LANES), lambda i: (0, i, 0))],
+            pl.BlockSpec((n_out, block_rows, LANES), lambda i: (0, i, 0)))
+
+
+# ---------------------------------------------------------------------------
 # Streaming config argmin (Algorithm 1 line 3)
 # ---------------------------------------------------------------------------
 
-def _config_kernel(qv_ref, b_ref, c_ref, eff_ref, acc_ref, xi_ref, size_ref,
-                   r_ref, m_ref, pol_ref, *, n_total: int, n_m: int,
-                   n_r: int):
+def _config_kernel(qv_ref, xi_ref, size_ref, b_ref, c_ref, eff_ref, acc_ref,
+                   out_ref, *, n_total: int, n_m: int, n_r: int):
     q = qv_ref[0, 0]
     v = qv_ref[0, 1]
-    b = b_ref[...]
     c = c_ref[...]
-    eff = eff_ref[...]
-    size = size_ref[...]
-    bn = b.shape[0]
-    lam = (b * eff)[:, None] / size[None, :]               # [bn, R]
-    r_iota = jax.lax.broadcasted_iota(jnp.int32, (bn, n_r), 1)
+    be = b_ref[...] * eff_ref[...]
 
-    best_val = jnp.full((bn,), jnp.inf, jnp.float32)
-    best_flat = jnp.zeros((bn,), jnp.int32)
-    for m in range(n_m):                                   # static on-chip loop
-        mu = c[:, None] / xi_ref[m, :][None, :]            # [bn, R]
-        acc_m = acc_ref[:, m, :]                           # [bn, R]
-        p = jnp.maximum(acc_m, 1e-3)
-        s_f = (v * aopi.aopi_fcfs(lam, mu, p) - q * acc_m) / n_total
-        s_l = (v * aopi.aopi_lcfsp(lam, mu, p) - q * acc_m) / n_total
-        # Per resolution, LCFSP only wins a tie-free strict comparison —
-        # flat order is (r, policy), FCFS first, matching the reference.
-        l_wins = s_l < s_f
-        val = jnp.where(l_wins, s_l, s_f)                  # [bn, R]
-        pol_r = l_wins.astype(jnp.int32)
-        min_val = jnp.min(val, axis=1, keepdims=True)
-        first_r = jnp.min(jnp.where(val == min_val, r_iota, n_r), axis=1)
-        sel = r_iota == first_r[:, None]
-        loc_val = jnp.sum(jnp.where(sel, val, 0.0), axis=1)
-        loc_pol = jnp.sum(jnp.where(sel, pol_r, 0), axis=1)
-        loc_flat = m * (n_r * 2) + first_r * 2 + loc_pol
-        take = loc_val < best_val                          # keeps earliest m
-        best_val = jnp.where(take, loc_val, best_val)
-        best_flat = jnp.where(take, loc_flat, best_flat)
+    def model(m, state):
+        best_val, best_flat = state
+        for r in range(n_r):                               # static
+            lam = be / size_ref[0, r]
+            mu = c / xi_ref[m, r]
+            acc_mr = acc_ref[m * n_r + r]                  # [rows, 128]
+            p = jnp.maximum(acc_mr, 1e-3)
+            # Flat order is (m, r, policy), FCFS first; the strict-<
+            # fold keeps the first minimal index like the flat argmin.
+            for pol, a in enumerate((aopi.aopi_fcfs(lam, mu, p),
+                                     aopi.aopi_lcfsp(lam, mu, p))):
+                score = (v * a - q * acc_mr) / n_total
+                take = score < best_val
+                best_val = jnp.where(take, score, best_val)
+                best_flat = jnp.where(take, (m * n_r + r) * 2 + pol,
+                                      best_flat)
+        return best_val, best_flat
 
-    m_ref[...] = best_flat // (n_r * 2)
-    r_ref[...] = (best_flat // 2) % n_r
-    pol_ref[...] = best_flat % 2
+    init = (jnp.full(c.shape, jnp.inf, jnp.float32),
+            jnp.zeros(c.shape, jnp.int32))
+    _, best_flat = jax.lax.fori_loop(0, n_m, model, init)
+    out_ref[0] = (best_flat // 2) % n_r
+    out_ref[1] = best_flat // (n_r * 2)
+    out_ref[2] = best_flat % 2
 
 
-@functools.partial(jax.jit, static_argnames=("n_total", "block_n",
+@functools.partial(jax.jit, static_argnames=("n_total", "block_rows",
                                              "interpret"))
 def config_argmin(b, c, acc, xi, size, eff, q, v, *, n_total: int,
-                  block_n: int = 1024, interpret: bool = False):
-    """Streaming (m, r, policy) argmin; returns ``(r_idx, m_idx, pol)``."""
-    n, n_m, n_r = acc.shape
-    block_n = min(block_n, n)
-    grid = (pl.cdiv(n, block_n),)
+                  block_rows: int, interpret: bool = False):
+    """Streaming (m, r, policy) argmin in the camera-on-lanes layout.
+
+    ``b``/``c``/``eff`` are ``[rows, 128]`` (camera ``i`` at
+    ``(i // 128, i % 128)``), ``acc`` is ``[M*R, rows, 128]`` and ``rows``
+    a multiple of ``block_rows``. Returns ``[3, rows, 128]`` int32 rows
+    ``(r_idx, m_idx, pol)``. Every intermediate is a dense
+    ``[block_rows, 128]`` tile, so VMEM per grid step is the
+    ``M*R``-plane accuracy block plus a few vregs, at any fleet size.
+    """
+    n_m, n_r = xi.shape
+    rows = b.shape[0]
     qv = jnp.stack([jnp.asarray(q, jnp.float32),
                     jnp.asarray(v, jnp.float32)]).reshape(1, 2)
     kernel = functools.partial(_config_kernel, n_total=n_total, n_m=n_m,
                                n_r=n_r)
-    out = pl.pallas_call(
+    in_specs, out_spec = _scan_specs(3, n_m * n_r, 3, block_rows)
+    return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda i: (0, 0),
-                         memory_space=pltpu.SMEM),               # q, V
-            pl.BlockSpec((block_n,), lambda i: (i,)),            # b
-            pl.BlockSpec((block_n,), lambda i: (i,)),            # c
-            pl.BlockSpec((block_n,), lambda i: (i,)),            # eff
-            pl.BlockSpec((block_n, n_m, n_r), lambda i: (i, 0, 0)),  # acc
-            pl.BlockSpec((n_m, n_r), lambda i: (0, 0)),          # xi
-            pl.BlockSpec((n_r,), lambda i: (0,)),                # size
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32)] * 3,
+        grid=(rows // block_rows,),
+        in_specs=in_specs,
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct((3, rows, LANES), jnp.int32),
         interpret=interpret,
-    )(qv, b, c, eff, acc, xi, size)
-    return tuple(out)
+    )(qv, xi, size.reshape(1, n_r), b, c, eff, acc)
 
 
 # ---------------------------------------------------------------------------
 # Per-server on-chip water-filling (Algorithm 1 lines 4/5)
 # ---------------------------------------------------------------------------
+
+#: The water-fills keep the ``[S, Np]`` (tiled: ``[S, tile]``) membership
+#: matrix and its products in VMEM at once — 4 MiB each at 10^4 cameras
+#: on 100 servers — so they may grow past v5e's 16 MiB default scope, up
+#: to 100 MiB of its 128 MiB.
+_VMEM_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=100 * 2**20)
 
 def _h_fn(x, scale, p, is_l, other, mode):
     """Marginal-AoPI water-level function h(x) (shared by every variant)."""
@@ -194,12 +218,12 @@ def _illinois_waterfill(scale, p, is_l, other, lo, hi, cf, member, *,
     n_servers = member.shape[0]
 
     def per_camera(v_s):
-        """Broadcast a per-server [S, 1] value to cameras [Np] (zero on
+        """Broadcast a per-server [S, 1] value to cameras [1, Np] (zero on
         padding slots, whose membership column is all-zero)."""
-        return jnp.sum(member * v_s, axis=0)
+        return jnp.sum(member * v_s, axis=0, keepdims=True)
 
     def alloc_at(log_nu_s, blo, bhi, iters):
-        nu = per_camera(jnp.exp(log_nu_s))                # [Np] duals
+        nu = per_camera(jnp.exp(log_nu_s))                # [1, Np] duals
         x_cl = jnp.sqrt(cf / jnp.maximum(scale * nu, _EPS))
         x_bi = solve_h_equals_nu(nu, blo, bhi, iters)
         return jnp.clip(jnp.where(is_l, x_cl, x_bi), lo, hi)
@@ -211,8 +235,7 @@ def _illinois_waterfill(scale, p, is_l, other, lo, hi, cf, member, *,
     def fill_at(log_nu_s, xa, xb, iters):
         blo, bhi = bracket(xa, xb)
         x = alloc_at(log_nu_s, blo, bhi, iters)
-        f = jnp.sum(member * x[None, :], axis=1,
-                    keepdims=True) - 1.0                  # [S, 1]
+        f = jnp.sum(member * x, axis=1, keepdims=True) - 1.0  # [S, 1]
         return x, f
 
     a0 = jnp.full((n_servers, 1), _LOG_NU_LO, jnp.float32)
@@ -265,25 +288,21 @@ def waterfill(scale, p, pol, other, lo, hi, cf, member, *, mode: str,
     (contiguous-per-server, lane-padded) order and ``member`` is the
     layout's ``[S, Np]`` membership matrix (``ops.ServerLayout.member``).
     Returns normalized allocations ``[Np]`` in the same order. One grid
-    program holds the whole fleet in VMEM (~9 f32 vectors + the
-    membership matrix — N up to ~10^5 at edge-scale server counts).
+    program holds the whole fleet in VMEM (the vectors as lane-dense
+    ``[1, Np]`` rows, so the membership reductions have a 2-D layout).
     """
     cap = scale.shape[0]
-    n_servers = member.shape[0]
     kernel = functools.partial(_waterfill_kernel, mode=mode,
                                outer_iters=outer_iters,
                                inner_iters=inner_iters,
                                final_inner_iters=final_inner_iters)
-    vec = pl.BlockSpec((cap,), lambda: (0,))
+    vecs = [x.reshape(1, cap) for x in (scale, p, pol, other, lo, hi, cf)]
     return pl.pallas_call(
         kernel,
-        grid=(),
-        in_specs=[vec] * 7 + [pl.BlockSpec((n_servers, cap),
-                                           lambda: (0, 0))],
-        out_specs=vec,
-        out_shape=jax.ShapeDtypeStruct((cap,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, cap), jnp.float32),
+        compiler_params=_VMEM_PARAMS,
         interpret=interpret,
-    )(scale, p, pol, other, lo, hi, cf, member)
+    )(*vecs, member).reshape(cap)
 
 
 # ---------------------------------------------------------------------------
@@ -295,7 +314,7 @@ def _pair_kernel(margin_ref, scale_b_ref, p_ref, pol_ref, mu_ref, lo_b_ref,
                  *, outer_iters: int, inner_iters: int,
                  final_inner_iters: int):
     margin = margin_ref[0, 0]                             # FCFS stability
-    scale_b = scale_b_ref[...]                            # k * B  [Np]
+    scale_b = scale_b_ref[...]                            # k * B  [1, Np]
     p = p_ref[...]
     is_l = pol_ref[...] == aopi.LCFSP
     member = member_ref[...]                              # [S, Np] 0/1
@@ -314,10 +333,10 @@ def _pair_kernel(margin_ref, scale_b_ref, p_ref, pol_ref, mu_ref, lo_b_ref,
     mu_scale = mu_scale_ref[...]                          # inv_xi * C
     floor = jnp.where(is_l, 1e-9,
                       margin * lam / jnp.maximum(mu_scale, _EPS))
-    floor_tot = jnp.sum(member * floor[None, :], axis=1,
-                        keepdims=True)                    # [S, 1]
+    floor_tot = jnp.sum(member * floor, axis=1, keepdims=True)  # [S, 1]
     scale_fac = jnp.minimum(1.0, 1.0 / jnp.maximum(floor_tot, _EPS))
-    lo_c = jnp.clip(floor * jnp.sum(member * scale_fac, axis=0), 1e-9, 1.0)
+    lo_c = jnp.clip(floor * jnp.sum(member * scale_fac, axis=0,
+                                    keepdims=True), 1e-9, 1.0)
 
     v = _illinois_waterfill(
         mu_scale, p, is_l, lam, lo_c, jnp.ones_like(lo_c), 1.0 / p, member,
@@ -343,22 +362,21 @@ def waterfill_pair(scale_b, p, pol, mu, lo_b, hi_b, cf_b, mu_scale, member,
     normalized ``(u, v)`` allocations in layout order.
     """
     cap = scale_b.shape[0]
-    n_servers = member.shape[0]
     kernel = functools.partial(_pair_kernel, outer_iters=outer_iters,
                                inner_iters=inner_iters,
                                final_inner_iters=final_inner_iters)
-    vec = pl.BlockSpec((cap,), lambda: (0,))
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     mg = jnp.asarray(stability_margin, jnp.float32).reshape(1, 1)
-    return pl.pallas_call(
+    vecs = [x.reshape(1, cap)
+            for x in (scale_b, p, pol, mu, lo_b, hi_b, cf_b, mu_scale)]
+    u, v = pl.pallas_call(
         kernel,
-        grid=(),
-        in_specs=[pl.BlockSpec((1, 1), lambda: (0, 0),
-                               memory_space=pltpu.SMEM)] + [vec] * 8 +
-                 [pl.BlockSpec((n_servers, cap), lambda: (0, 0))],
-        out_specs=[vec, vec],
-        out_shape=[jax.ShapeDtypeStruct((cap,), jnp.float32)] * 2,
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [vmem] * 9,
+        out_shape=[jax.ShapeDtypeStruct((1, cap), jnp.float32)] * 2,
+        compiler_params=_VMEM_PARAMS,
         interpret=interpret,
-    )(mg, scale_b, p, pol, mu, lo_b, hi_b, cf_b, mu_scale, member)
+    )(mg, *vecs, member)
+    return u.reshape(cap), v.reshape(cap)
 
 
 # ---------------------------------------------------------------------------
@@ -367,6 +385,9 @@ def waterfill_pair(scale_b, p, pol, mu, lo_b, hi_b, cf_b, mu_scale, member,
 
 # Row order of the packed [8, Np] input block (built by ops._run_waterfill).
 TILE_FIELDS = ("scale", "p", "is_l", "other", "lo", "hi", "cf", "sid")
+# The bracket scratch holds (xa, xb, x_last); Mosaic tiles a short f32 HBM
+# array in 4-row groups and refuses a 3-row DMA slice, so it has a pad row.
+_BRACKET_ROWS = 4
 
 
 def _tiled_waterfill_kernel(in_hbm, x_hbm, st_hbm, *, mode: str,
@@ -379,7 +400,7 @@ def _tiled_waterfill_kernel(in_hbm, x_hbm, st_hbm, *, mode: str,
     holds a double-buffered ``[2, 8, tile]`` window of it. Per-server
     Illinois state (duals, residuals, the deferred bracket decision) stays
     in ``[S, 1]`` registers across the sweep; per-camera brackets
-    ``(xa, xb, x_last)`` persist in a ``[3, Np]`` HBM scratch between
+    ``(xa, xb, x_last)`` persist in a ``[4, Np]`` HBM scratch between
     sweeps, so VMEM holds only O(tile) state no matter the fleet size.
     One dual evaluation = one sweep over the tiles accumulating the
     per-server fill sums.
@@ -392,7 +413,8 @@ def _tiled_waterfill_kernel(in_hbm, x_hbm, st_hbm, *, mode: str,
     """
 
     def body(in_scr, st_scr, out_scr, in_sems, st_sem, out_sem):
-        srv = jax.lax.broadcasted_iota(jnp.float32, (n_servers, tile), 0)
+        srv = jax.lax.broadcasted_iota(jnp.int32, (n_servers, tile),
+                                       0).astype(jnp.float32)
 
         def in_dma(slot, t):
             return pltpu.make_async_copy(
@@ -413,14 +435,13 @@ def _tiled_waterfill_kernel(in_hbm, x_hbm, st_hbm, *, mode: str,
 
                 in_dma(slot, t).wait()
                 blk = in_scr[slot]                        # [8, tile]
-                scale, p = blk[0], blk[1]
-                is_l = blk[2] > 0.5
-                other, lo, hi, cf, sid = (blk[3], blk[4], blk[5], blk[6],
-                                          blk[7])
-                member = (sid[None, :] == srv).astype(jnp.float32)
+                scale, p, is_l, other, lo, hi, cf, sid = (
+                    blk[i:i + 1] for i in range(len(TILE_FIELDS)))
+                is_l = is_l > 0.5
+                member = (sid == srv).astype(jnp.float32)
 
                 def per_camera(v_s):
-                    return jnp.sum(member * v_s, axis=0)
+                    return jnp.sum(member * v_s, axis=0, keepdims=True)
 
                 def alloc_at(log_nu_s, blo, bhi, it):
                     nu = per_camera(jnp.exp(log_nu_s))
@@ -444,7 +465,7 @@ def _tiled_waterfill_kernel(in_hbm, x_hbm, st_hbm, *, mode: str,
                             jnp.minimum(hi, xa + pad))
 
                 def fill_of(x):
-                    return jnp.sum(member * x[None, :], axis=1,
+                    return jnp.sum(member * x, axis=1,
                                    keepdims=True)          # [S, 1]
 
                 if phase == 0:
@@ -452,9 +473,9 @@ def _tiled_waterfill_kernel(in_hbm, x_hbm, st_hbm, *, mode: str,
                     blo, bhi = bracket(hi, lo)
                     xa = alloc_at(la, blo, bhi, iters)
                     xb = alloc_at(lb, blo, bhi, iters)
-                    st_scr[0, :] = xa
-                    st_scr[1, :] = xb
-                    st_scr[2, :] = xb
+                    st_scr[0:1, :] = xa
+                    st_scr[1:2, :] = xb
+                    st_scr[2:3, :] = xb
                     wr = pltpu.make_async_copy(
                         st_scr, st_hbm.at[:, pl.ds(t * tile, tile)], st_sem)
                     wr.start()
@@ -468,20 +489,20 @@ def _tiled_waterfill_kernel(in_hbm, x_hbm, st_hbm, *, mode: str,
                 # Apply the previous evaluation's over/under decision to
                 # the stored brackets (same update as the untiled carry).
                 ov = per_camera(over_prev) > 0.5
-                xa = jnp.where(ov, st_scr[2], st_scr[0])
-                xb = jnp.where(ov, st_scr[1], st_scr[2])
+                xa = jnp.where(ov, st_scr[2:3], st_scr[0:1])
+                xb = jnp.where(ov, st_scr[1:2], st_scr[2:3])
                 blo, bhi = bracket(xa, xb)
                 x = alloc_at(log_nu, blo, bhi, iters)
                 if phase == 1:
-                    st_scr[0, :] = xa
-                    st_scr[1, :] = xb
-                    st_scr[2, :] = x
+                    st_scr[0:1, :] = xa
+                    st_scr[1:2, :] = xb
+                    st_scr[2:3, :] = x
                     wr = pltpu.make_async_copy(
                         st_scr, st_hbm.at[:, pl.ds(t * tile, tile)], st_sem)
                     wr.start()
                     wr.wait()
                     return fs[0] + fill_of(x), fs[1]
-                out_scr[0, :] = x
+                out_scr[...] = x
                 wr = pltpu.make_async_copy(
                     out_scr, x_hbm.at[:, pl.ds(t * tile, tile)], out_sem)
                 wr.start()
@@ -546,7 +567,7 @@ def waterfill_tiled(block, *, mode: str, n_servers: int, tile: int,
         pl.run_scoped(
             inner,
             in_scr=pltpu.VMEM((2, f, tile), jnp.float32),
-            st_scr=pltpu.VMEM((3, tile), jnp.float32),
+            st_scr=pltpu.VMEM((_BRACKET_ROWS, tile), jnp.float32),
             out_scr=pltpu.VMEM((1, tile), jnp.float32),
             in_sems=pltpu.SemaphoreType.DMA((2,)),
             st_sem=pltpu.SemaphoreType.DMA,
@@ -556,10 +577,12 @@ def waterfill_tiled(block, *, mode: str, n_servers: int, tile: int,
     x, _ = pl.pallas_call(
         kernel,
         grid=(),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 2,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_shape=[jax.ShapeDtypeStruct((1, np_), jnp.float32),
-                   jax.ShapeDtypeStruct((3, np_), jnp.float32)],
+                   jax.ShapeDtypeStruct((_BRACKET_ROWS, np_),
+                                        jnp.float32)],
+        compiler_params=_VMEM_PARAMS,
         interpret=interpret,
     )(block)
     return x[0]
@@ -569,60 +592,57 @@ def waterfill_tiled(block, *, mode: str, n_servers: int, tile: int,
 # Streaming DOS/JCAB config scans (core.baselines)
 # ---------------------------------------------------------------------------
 
-def _baseline_kernel(sc_ref, b_ref, c_ref, eff_ref, acc_ref, xi_ref,
-                     size_ref, m_ref, r_ref, *, mode: str, n_m: int,
-                     n_r: int):
-    thresh = sc_ref[0, 0]           # DOS latency weight / JCAB latency cap
-    b = b_ref[...]
+def _baseline_kernel(th_ref, xi_ref, size_ref, b_ref, c_ref, eff_ref,
+                     acc_ref, out_ref, *, mode: str, n_m: int, n_r: int):
+    thresh = th_ref[0, 0]           # DOS latency weight / JCAB latency cap
     c = c_ref[...]
-    eff = eff_ref[...]
-    size = size_ref[...]
-    bn = b.shape[0]
-    lam = (b * eff)[:, None] / size[None, :]               # [bn, R]
-    inv_lam = 1.0 / jnp.maximum(lam, 1e-9)
-    r_iota = jax.lax.broadcasted_iota(jnp.int32, (bn, n_r), 1)
+    be = b_ref[...] * eff_ref[...]
 
-    best_val = jnp.full((bn,), -jnp.inf, jnp.float32)
-    best_flat = jnp.zeros((bn,), jnp.int32)
-    # JCAB fallback: the overall min-latency config, tracked alongside.
-    lat_best = jnp.full((bn,), jnp.inf, jnp.float32)
-    lat_flat = jnp.zeros((bn,), jnp.int32)
-    for m in range(n_m):                                   # static on-chip
-        mu = c[:, None] / xi_ref[m, :][None, :]            # [bn, R]
-        latency = inv_lam + 1.0 / jnp.maximum(mu, 1e-9)
-        acc_m = acc_ref[:, m, :]                           # [bn, R]
-        if mode == "dos":
-            val = acc_m - thresh * latency
-        else:
-            val = jnp.where(latency <= thresh, acc_m, -jnp.inf)
-        # First-max within the row, then strict-> across models: the fold
-        # keeps the earliest flat index, matching jnp.argmax exactly.
-        row_max = jnp.max(val, axis=1, keepdims=True)
-        first_r = jnp.min(jnp.where(val == row_max, r_iota, n_r), axis=1)
-        take = row_max[:, 0] > best_val
-        best_val = jnp.where(take, row_max[:, 0], best_val)
-        best_flat = jnp.where(take, m * n_r + first_r, best_flat)
-        if mode == "jcab":
-            lat_min = jnp.min(latency, axis=1, keepdims=True)
-            first_l = jnp.min(jnp.where(latency == lat_min, r_iota, n_r),
-                              axis=1)
-            lt = lat_min[:, 0] < lat_best
-            lat_best = jnp.where(lt, lat_min[:, 0], lat_best)
-            lat_flat = jnp.where(lt, m * n_r + first_l, lat_flat)
+    def model(m, state):
+        best_val, best_flat, lat_best, lat_flat = state
+        for r in range(n_r):                               # static
+            lam = be / size_ref[0, r]
+            mu = c / xi_ref[m, r]
+            latency = 1.0 / jnp.maximum(lam, 1e-9) + 1.0 / jnp.maximum(mu,
+                                                                       1e-9)
+            acc_mr = acc_ref[m * n_r + r]
+            if mode == "dos":
+                val = acc_mr - thresh * latency
+            else:
+                val = jnp.where(latency <= thresh, acc_mr, -jnp.inf)
+            flat = m * n_r + r
+            # Strict-> fold in flat (m-major) order keeps the earliest
+            # maximal index, matching jnp.argmax exactly.
+            take = val > best_val
+            best_val = jnp.where(take, val, best_val)
+            best_flat = jnp.where(take, flat, best_flat)
+            if mode == "jcab":
+                # Min-latency fallback config, tracked alongside.
+                lt = latency < lat_best
+                lat_best = jnp.where(lt, latency, lat_best)
+                lat_flat = jnp.where(lt, flat, lat_flat)
+        return best_val, best_flat, lat_best, lat_flat
 
+    zeros = jnp.zeros(c.shape, jnp.int32)
+    init = (jnp.full(c.shape, -jnp.inf, jnp.float32), zeros,
+            jnp.full(c.shape, jnp.inf, jnp.float32), zeros)
+    best_val, best_flat, _, lat_flat = jax.lax.fori_loop(0, n_m, model, init)
     if mode == "jcab":
         # No config met the cap anywhere: min-latency fallback (the jnp
         # twin's argmax over all -inf also lands on flat index 0, so the
         # met-somewhere case needs no special handling).
-        best_flat = jnp.where(jnp.isneginf(best_val), lat_flat, best_flat)
-    m_ref[...] = best_flat // n_r
-    r_ref[...] = best_flat % n_r
+        best_flat = jnp.where(best_val == -jnp.inf, lat_flat, best_flat)
+    out_ref[0] = best_flat // n_r
+    out_ref[1] = best_flat % n_r
 
 
-@functools.partial(jax.jit, static_argnames=("mode", "block_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=("mode", "block_rows",
+                                             "interpret"))
 def baseline_argmax(b, c, acc, xi, size, eff, *, mode: str, threshold,
-                    block_n: int = 1024, interpret: bool = False):
-    """Streaming DOS/JCAB config argmax; returns ``(m_idx, r_idx)``.
+                    block_rows: int, interpret: bool = False):
+    """Streaming DOS/JCAB config argmax in the camera-on-lanes layout of
+    :func:`config_argmin`; returns ``[2, rows, 128]`` int32 rows
+    ``(m_idx, r_idx)``.
 
     ``mode="dos"`` maximizes ``acc - threshold * latency``;
     ``mode="jcab"`` maximizes accuracy among configs with
@@ -630,30 +650,17 @@ def baseline_argmax(b, c, acc, xi, size, eff, *, mode: str, threshold,
     when none qualifies. Bitwise-identical indices to the materialized
     jnp scans (same elementwise ops, same first-index tie-breaks).
     """
-    n, n_m, n_r = acc.shape
-    block_n = min(block_n, n)
-    grid = (pl.cdiv(n, block_n),)
-    sc = jnp.asarray(threshold, jnp.float32).reshape(1, 1)
+    n_m, n_r = xi.shape
+    rows = b.shape[0]
+    th = jnp.asarray(threshold, jnp.float32).reshape(1, 1)
     kernel = functools.partial(_baseline_kernel, mode=mode, n_m=n_m,
                                n_r=n_r)
-    out = pl.pallas_call(
+    in_specs, out_spec = _scan_specs(3, n_m * n_r, 2, block_rows)
+    return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0),
-                         memory_space=pltpu.SMEM),               # threshold
-            pl.BlockSpec((block_n,), lambda i: (i,)),            # b
-            pl.BlockSpec((block_n,), lambda i: (i,)),            # c
-            pl.BlockSpec((block_n,), lambda i: (i,)),            # eff
-            pl.BlockSpec((block_n, n_m, n_r), lambda i: (i, 0, 0)),  # acc
-            pl.BlockSpec((n_m, n_r), lambda i: (0, 0)),          # xi
-            pl.BlockSpec((n_r,), lambda i: (0,)),                # size
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32)] * 2,
+        grid=(rows // block_rows,),
+        in_specs=in_specs,
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct((2, rows, LANES), jnp.int32),
         interpret=interpret,
-    )(sc, b, c, eff, acc, xi, size)
-    return tuple(out)
+    )(th, xi, size.reshape(1, n_r), b, c, eff, acc)

@@ -140,9 +140,46 @@ def server_layout(server_id, n_servers: int,
 # Config selection (Algorithm 1 line 3)
 # ---------------------------------------------------------------------------
 
+#: Cameras per grid step of the config scans: the ``[M*R, 32, 128]`` f32
+#: accuracy block is 864 KiB at the paper's 9 x 6 grid, double-buffered
+#: well inside v5e's 16 MiB scoped VMEM at every fleet size.
+CONFIG_BLOCK_N = 4096
+
+
+def _scan_layout(n: int, block_n: int) -> tuple[int, int]:
+    """``(rows, block_rows)`` of the camera-on-lanes scan layout: ``rows``
+    128-camera rows, padded to a whole number of grid blocks."""
+    rows = -(-n // kernel.LANES)
+    block_rows = kernel.block_rows_for(rows, block_n)
+    return -(-rows // block_rows) * block_rows, block_rows
+
+
+def _camera_lanes(x, rows: int):
+    """``[N]`` -> ``[rows, 128]`` (padding cameras get a benign 1.0)."""
+    x = jnp.asarray(x, jnp.float32)
+    pad = rows * kernel.LANES - x.shape[0]
+    return jnp.pad(x, (0, pad), constant_values=1.0).reshape(
+        rows, kernel.LANES)
+
+
+def _acc_planes(acc, rows: int):
+    """``[N, M, R]`` -> ``[M*R, rows, 128]``: one camera-on-lanes plane per
+    (model, resolution) config, flat in (m, r) order."""
+    n = acc.shape[0]
+    flat = jnp.asarray(acc, jnp.float32).reshape(n, -1).T
+    return jnp.pad(flat, ((0, 0), (0, rows * kernel.LANES - n)),
+                   constant_values=1.0).reshape(-1, rows, kernel.LANES)
+
+
+def _scan_operands(b, c, acc, eff, block_n: int):
+    rows, block_rows = _scan_layout(acc.shape[0], block_n)
+    return ((_camera_lanes(b, rows), _camera_lanes(c, rows),
+             _acc_planes(acc, rows), _camera_lanes(eff, rows)), block_rows)
+
+
 def config_argmin(b, c, acc, xi, size, eff, q, v, n_total: int,
                   backend: str = "jnp", interpret: bool | None = None,
-                  block_n: int = 1024):
+                  block_n: int = CONFIG_BLOCK_N):
     """Per-camera (r_idx, m_idx, pol) minimizing the drift-plus-penalty
     score over the (model x resolution x policy) grid."""
     if backend == "jnp":
@@ -151,14 +188,18 @@ def config_argmin(b, c, acc, xi, size, eff, q, v, n_total: int,
         raise ValueError(f"unknown solver backend {backend!r};"
                          " known: ('jnp', 'pallas')")
     obs.count_dispatch("config_argmin")
-    return kernel.config_argmin(b, c, acc, xi, size, eff, q, v,
-                                n_total=n_total, block_n=block_n,
-                                interpret=_resolve_interpret(interpret))
+    n = acc.shape[0]
+    (bl, cl, al, el), block_rows = _scan_operands(b, c, acc, eff, block_n)
+    out = kernel.config_argmin(bl, cl, al, xi, size, el, q, v,
+                               n_total=n_total, block_rows=block_rows,
+                               interpret=_resolve_interpret(interpret))
+    out = out.reshape(3, -1)[:, :n]
+    return out[0], out[1], out[2]
 
 
 def baseline_argmax(b, c, acc, xi, size, eff, *, mode: str, threshold,
                     backend: str = "jnp", interpret: bool | None = None,
-                    block_n: int = 1024):
+                    block_n: int = CONFIG_BLOCK_N):
     """Streaming DOS/JCAB config scan; returns per-camera ``(m_idx, r_idx)``.
 
     The jnp backend materializes the ``[N, M, R]`` latency/score tensors
@@ -173,9 +214,13 @@ def baseline_argmax(b, c, acc, xi, size, eff, *, mode: str, threshold,
         raise ValueError(f"unknown solver backend {backend!r};"
                          " known: ('jnp', 'pallas')")
     obs.count_dispatch("baseline_argmax", mode=str(mode))
-    return kernel.baseline_argmax(b, c, acc, xi, size, eff, mode=mode,
-                                  threshold=threshold, block_n=block_n,
-                                  interpret=_resolve_interpret(interpret))
+    n = acc.shape[0]
+    (bl, cl, al, el), block_rows = _scan_operands(b, c, acc, eff, block_n)
+    out = kernel.baseline_argmax(bl, cl, al, xi, size, el, mode=mode,
+                                 threshold=threshold, block_rows=block_rows,
+                                 interpret=_resolve_interpret(interpret))
+    out = out.reshape(2, -1)[:, :n]
+    return out[0], out[1]
 
 
 # ---------------------------------------------------------------------------

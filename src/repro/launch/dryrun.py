@@ -46,16 +46,6 @@ _BYTES = {"bf16": 2, "f16": 2, "f32": 4, "f64": 8, "s32": 4, "u32": 4,
           "s8": 1, "u8": 1, "pred": 1, "s64": 8}
 
 
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across jax versions: older
-    releases return a list with one dict per program, newer ones a plain
-    dict. Always returns a dict (empty when the backend reports nothing)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
 def _type_bytes(text: str) -> int:
     total = 0
     for m in _SHAPE_RE.finditer(text):
@@ -123,7 +113,7 @@ def measure_cell(cfg, shape, mesh, *, skip_extrapolation=False,
         "temp_gib": ma.temp_size_in_bytes / 2**30,
         "alias_gib": ma.alias_size_in_bytes / 2**30,
     }
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     rec["cost_full_hlo"] = {"flops": ca.get("flops", 0.0),
                             "bytes": ca.get("bytes accessed", 0.0)}
     rec["collectives_full_hlo"] = collective_bytes(compiled.as_text())
@@ -145,7 +135,7 @@ def measure_cell(cfg, shape, mesh, *, skip_extrapolation=False,
         probe_kwargs["n_microbatches"] = 1
         dplan = plan_cell(dcfg, shape, mesh, **probe_kwargs)
         dcomp = dplan.lower().compile()
-        dca = cost_analysis(dcomp)
+        dca = dcomp.cost_analysis()
         vals[depth] = {
             "flops": dca.get("flops", 0.0),
             "bytes": dca.get("bytes accessed", 0.0),

@@ -3,41 +3,20 @@
 Defined as FUNCTIONS (never module-level constants) so importing this module
 never touches jax device state — required because the dry-run must set
 XLA_FLAGS before any jax initialization.
-
-:func:`make_mesh` is the version-compat front door: newer jax exposes
-``jax.sharding.AxisType`` and ``jax.make_mesh(..., axis_types=...)``;
-older releases (e.g. 0.4.x) have neither. Every mesh in the repo (and the
-tier-1 tests) goes through this shim so the code runs on both.
 """
 from __future__ import annotations
 
 import jax
 
 
-def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None):
-    """``jax.make_mesh`` with ``AxisType`` resolved per jax version.
-
-    ``axis_types`` may be None (defaults to ``Auto`` on every axis when
-    the running jax supports axis types), a tuple of
-    ``jax.sharding.AxisType`` members, or a tuple of their lowercase
-    names (``"auto"`` / ``"explicit"`` / ``"manual"``) so call sites can
-    stay importable on jax versions without the enum. On a jax without
-    ``AxisType`` the argument is dropped entirely — positional fallback
-    — which matches the old default behavior.
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    axis_type_cls = getattr(jax.sharding, "AxisType", None)
-    if axis_type_cls is not None:
-        if axis_types is None:
-            axis_types = (axis_type_cls.Auto,) * len(tuple(axis_names))
-        else:
-            axis_types = tuple(
-                getattr(axis_type_cls, t.capitalize())
-                if isinstance(t, str) else t for t in axis_types)
-        kwargs["axis_types"] = axis_types
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` (shardings
+    propagated by the compiler, as every mesh in the repo expects)."""
+    names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(axis_shapes), names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+        devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -146,7 +146,6 @@ def _moe_apply_a2a(params, x, cfg, capacity, mesh, ep_ax, dp_axes, rules):
     it is written explicitly here. Routing math is per-sequence and hence
     bit-identical to the einsum path.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as Ps
 
     ep = mesh.shape[ep_ax]
@@ -186,11 +185,11 @@ def _moe_apply_a2a(params, x, cfg, capacity, mesh, ep_ax, dp_axes, rules):
 
     all_axes = tuple(mesh.axis_names)
     batch_spec = Ps(dp_axes, None, None)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         local, mesh=mesh,
         in_specs=(batch_spec, w_specs),
         out_specs=(batch_spec, Ps()),
-        check_rep=False)
+        check_vma=False)
     y, aux = mapped(x, expert_params)
 
     if "shared" in params:

@@ -16,10 +16,9 @@ a stacked scenario axis (a :class:`registry.Suite` or raw stacked
   * ``"fleet"`` — the same padded blocks dispatched asynchronously to each
     device through one shared jitted block function (JAX async dispatch
     keeps all devices busy). Every device runs a plain single-partition
-    program, so results agree with the vmap fallback to float32 ulp (the
-    block batch size differs from the full-K vmap call, so XLA may fuse
-    final reductions slightly differently — but no ``num_partitions > 1``
-    rewrite is involved and no decision flips have been observed). This
+    program, and the fleet means are reduced by a separate program (see
+    ``_vmapped``), so results agree bitwise with the vmap fallback
+    although the block batch size differs from the full-K vmap call. This
     is the backend the tight parity tests pin.
   * ``"vmap"`` (default on 1 device) — plain ``vmap`` over the scenario
     axis.
@@ -38,7 +37,6 @@ from typing import Mapping, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import obs
@@ -137,9 +135,10 @@ class SweepResult:
                                  self.predicted_by_model[delay_model][policy])
 
 
-def _reduced_policy(name: str, n_bcd_iters: int, solver_backend: str):
-    """One scenario's rollout -> [T] fleet means, with every policy knob a
-    traced scalar so one compiled program serves all knob values."""
+def _policy_rollout(name: str, n_bcd_iters: int, solver_backend: str):
+    """One scenario's rollout -> per-camera ``[T, N]`` AoPI and accuracy
+    and the ``[T]`` queue, with every policy knob a traced scalar so one
+    compiled program serves all knob values."""
     def fn(tables: HorizonTables, v, p_min, dos_weight, jcab_cap):
         if name == "lbcd":
             res = lbcd.rollout(tables, v, p_min, n_bcd_iters=n_bcd_iters,
@@ -157,16 +156,27 @@ def _reduced_policy(name: str, n_bcd_iters: int, solver_backend: str):
         else:
             raise ValueError(
                 f"unknown policy {name!r}; known: {POLICIES}")
-        if tables.active is not None:
-            # Churn-masked fleet: dead cameras carry exact zeros, so the
-            # fleet mean divides by the live count, not N.
-            n_live = jnp.maximum(tables.active.sum(axis=-1), 1.0)
-            return {"aopi": res.aopi.sum(axis=-1) / n_live,
-                    "acc": res.acc.sum(axis=-1) / n_live,
-                    "q": res.q}
-        return {"aopi": res.aopi.mean(axis=-1),
-                "acc": res.acc.mean(axis=-1),
-                "q": res.q}
+        return res.aopi, res.acc, res.q
+    return fn
+
+
+def _fleet_means(aopi, acc, q, active) -> dict:
+    """Per-camera series -> per-slot fleet means ``{"aopi", "acc", "q"}``."""
+    if active is not None:
+        # Churn-masked fleet: dead cameras carry exact zeros, so the
+        # fleet mean divides by the live count, not N.
+        n_live = jnp.maximum(active.sum(axis=-1), 1.0)
+        return {"aopi": aopi.sum(axis=-1) / n_live,
+                "acc": acc.sum(axis=-1) / n_live, "q": q}
+    return {"aopi": aopi.mean(axis=-1), "acc": acc.mean(axis=-1), "q": q}
+
+
+def _reduced_policy(name: str, n_bcd_iters: int, solver_backend: str):
+    """One scenario's rollout reduced to ``[T]`` fleet means."""
+    rollout = _policy_rollout(name, n_bcd_iters, solver_backend)
+
+    def fn(tables: HorizonTables, *knobs):
+        return _fleet_means(*rollout(tables, *knobs), tables.active)
     return fn
 
 
@@ -174,23 +184,35 @@ def _reduced_policy(name: str, n_bcd_iters: int, solver_backend: str):
 def _vmapped(name: str, n_bcd_iters: int, solver_backend: str):
     """The shared block program: vmap over scenarios, scalars broadcast.
     Cached so repeat sweeps (and the fleet backend's per-device dispatch)
-    reuse one compiled executable per (policy, shapes)."""
-    return jax.jit(jax.vmap(
-        _reduced_policy(name, n_bcd_iters, solver_backend),
+    reuse one compiled executable per (policy, shapes).
+
+    The fleet means are a second program over the per-camera series in
+    their default layout. Inside one program XLA lays intermediates out by
+    batch size (on a TPU a block of 11 scenarios put the scenario axis on
+    lanes, a block of 3 the cameras), which changes the order of the
+    camera-axis sum; split, the means of a block agree bitwise with the
+    same scenarios' means in any other block size."""
+    rollout = jax.jit(jax.vmap(
+        _policy_rollout(name, n_bcd_iters, solver_backend),
         in_axes=(0, None, None, None, None)))
+    means = jax.jit(_fleet_means)
+
+    def block(tables: HorizonTables, *knobs):
+        return means(*rollout(tables, *knobs), tables.active)
+    return block
 
 
 @functools.lru_cache(maxsize=None)
 def _sharded(name: str, n_bcd_iters: int, solver_backend: str,
              devices: tuple):
     mesh = Mesh(np.asarray(devices), ("scenario",))
-    # check_rep=False: jax has no replication rule for pallas_call; the
+    # check_vma=False: jax has no varying-axes rule for pallas_call; the
     # sweep has no collectives, so the check adds nothing here.
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         jax.vmap(_reduced_policy(name, n_bcd_iters, solver_backend),
                  in_axes=(0, None, None, None, None)),
         mesh=mesh, in_specs=(P("scenario"), P(), P(), P(), P()),
-        out_specs=P("scenario"), check_rep=False))
+        out_specs=P("scenario"), check_vma=False))
 
 
 def _pad_scenarios(tables: HorizonTables, pad: int) -> HorizonTables:

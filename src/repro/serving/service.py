@@ -66,6 +66,11 @@ def _policy_label(controller) -> str:
     return names.get(cls, cls.lower())
 
 
+#: Planning failures the degradation ladder absorbs (see
+#: ``AnalyticsService._plan_with_ladder``).
+_LADDER_FAILURES = (fault_plane.InjectedSolverFault, FloatingPointError,
+                    TimeoutError)
+
 #: Element budget (epochs x streams x frames) of one batched data-plane
 #: dispatch; larger windows are chunked along the epoch axis so peak
 #: device memory stays bounded (~a few hundred MB of f64 intermediates).
@@ -504,6 +509,11 @@ class AnalyticsService:
         Each failed attempt and each fallback appends to the legacy list
         *and* emits the matching ``repro.obs`` event in the same block, so
         counters and lists reconcile exactly.
+
+        Only the failures the ladder models engage it: an injected solver
+        fault, a NaN plan and the watchdog deadline. Any other exception
+        (a device program that fails to lower, compile or run, a bug)
+        propagates, so a broken planner is never served as a fallback.
         """
         for attempt in range(self.plan_retries + 1):
             try:
@@ -511,7 +521,7 @@ class AnalyticsService:
                 self._plan_degraded = None
                 self._last_plan = plan
                 return plan
-            except Exception as e:  # noqa: BLE001 — every rung must engage
+            except _LADDER_FAILURES as e:
                 err = f"{type(e).__name__}: {e}"
                 self.plan_failures.append((t, attempt, err))
                 obs.event("service.plan_retry", policy=self._policy,

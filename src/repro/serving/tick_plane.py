@@ -58,7 +58,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
 from .. import obs
 from ..core import queues
@@ -209,7 +208,7 @@ def measure_engine_window_scan(lam, mu, p, pol, *, epoch_duration: float,
     a_nxt = np.concatenate([arrive[:, 1:], np.full((s, 1), np.inf)], axis=1)
 
     with obs.span("tick_plane.window", delay_model=delay_model,
-                  epochs=e, streams=n, n_frames=f), enable_x64():
+                  epochs=e, streams=n, n_frames=f), jax.enable_x64(True):
         out, fin_state, ys = _tick_scan(
             jnp.asarray(T.T), jnp.asarray(O.T), jnp.asarray(coin.T),
             jnp.asarray(arrive.T), jnp.asarray(a_nxt.T),

@@ -113,13 +113,12 @@ def test_plan_cell_compiles_on_small_mesh():
         from repro.configs.base import SHAPES
         from repro.launch.specs import plan_cell
         from repro.launch.mesh import make_mesh
-        from repro.launch.dryrun import cost_analysis
         mesh = make_mesh((2,4), ("data","model"))
         for shape in ("train_4k", "decode_32k"):
             plan = plan_cell(configs.get("qwen2.5-3b"), SHAPES[shape],
                              mesh)
             c = plan.compile()
-            assert cost_analysis(c).get("flops", 0) > 0
+            assert c.cost_analysis().get("flops", 0) > 0
         print("PLAN OK")
     """)
     assert "PLAN OK" in out

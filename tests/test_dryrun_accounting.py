@@ -72,8 +72,7 @@ def test_depth_extrapolation_matches_unrolled():
                 tr.jax.lax.scan = orig
         else:
             c = jax.jit(loss).lower(params, batch).compile()
-        from repro.launch.dryrun import cost_analysis
-        return cost_analysis(c).get("flops", 0.0)
+        return c.cost_analysis().get("flops", 0.0)
 
     f1 = flops_at(1, False)     # <=2 periods auto-unrolls
     f2 = flops_at(2, False)

@@ -19,7 +19,7 @@ from repro import obs, scenarios
 from repro.core import allocate, aopi, baselines, lbcd, queues
 from repro.faults import (FaultPlan, FaultSpec, InjectedSolverFault,
                           SOLVER_KINDS, apply_plan, storm_plan)
-from repro.serving import replay
+from repro.serving import AnalyticsService, replay
 from repro.serving.replay import TableSystem, replay_suite, replay_tables
 
 DIMS = dict(n_cameras=4, n_slots=12, n_servers=2,
@@ -354,6 +354,44 @@ def test_plan_deadline_watchdog_trips_ladder():
     assert svc.fallbacks and all(f[0] is not None for f in svc.fallbacks)
     assert all("TimeoutError" in err for _, _, err in svc.plan_failures)
     assert np.isfinite(rep.measured).all()
+
+
+def _ladder_service(plan):
+    t = _tables("camera_churn", n_slots=16)
+    system = TableSystem(t)
+    ctrl = replay.make_controller("lbcd", system)
+    return AnalyticsService(ctrl, tables=system.horizon(16), plan_window=4,
+                            faults=plan)
+
+
+def test_unmodelled_planner_error_propagates(monkeypatch):
+    """Only the modelled failures engage the ladder: any other exception
+    out of ``plan_horizon`` (a device program that fails to lower,
+    compile or run) leaves ``run_epoch`` and no fallback plan is served."""
+    svc = _ladder_service(None)
+
+    def broken(k, t0=0):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(svc, "plan_horizon", broken)
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        svc.run_epoch(0)
+    assert svc.plan_failures == [] and svc.fallbacks == []
+
+
+def test_injected_solver_fault_walks_retry_stale_min():
+    """An injected fault on every attempt walks the whole ladder: retries
+    first, MIN with no good plan yet, the stale plan once one exists."""
+    plan = FaultPlan((FaultSpec("solver_nonconverge", t0=0, duration=1,
+                                params={"attempts": 64}),
+                      FaultSpec("solver_nonconverge", t0=8, duration=4,
+                                params={"attempts": 64})), seed=0)
+    svc = _ladder_service(plan)
+    svc.run(12)
+    assert [f[1] for f in svc.plan_failures if f[0] == 0] == [0, 1, 2]
+    assert svc.fallbacks == [(0, "min_fallback"), (8, "stale_plan")]
+    assert all(err.startswith("InjectedSolverFault")
+               for _, _, err in svc.plan_failures)
 
 
 # ---------------------------------------------------------------------------

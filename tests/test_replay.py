@@ -119,8 +119,8 @@ def test_plan_horizon_matches_rollout():
 
 
 def _top_level_eqns(jaxpr):
-    """Descend through single-eqn pjit wrappers to the body jaxpr."""
-    while len(jaxpr.eqns) == 1 and jaxpr.eqns[0].primitive.name == "pjit":
+    """Descend through single-eqn jit wrappers to the body jaxpr."""
+    while len(jaxpr.eqns) == 1 and jaxpr.eqns[0].primitive.name == "jit":
         jaxpr = jaxpr.eqns[0].params["jaxpr"].jaxpr
     return jaxpr.eqns
 

@@ -255,14 +255,12 @@ _SHARD_SCRIPT = textwrap.dedent("""
         and shard.backend == "shard_map[4]", \\
         (vmap_.backend, fleet.backend, shard.backend)
     for p in scenarios.POLICIES:
-        # fleet runs the identical per-block executable as the vmap
-        # fallback: summaries agree to float32 ulp, decisions exactly.
-        np.testing.assert_allclose(fleet.aopi[p], vmap_.aopi[p],
-                                   rtol=1e-6, atol=1e-8, err_msg=p)
-        np.testing.assert_allclose(fleet.acc[p], vmap_.acc[p],
-                                   rtol=1e-6, atol=1e-8, err_msg=p)
-        np.testing.assert_allclose(fleet.q[p], vmap_.q[p],
-                                   rtol=1e-6, atol=1e-7, err_msg=p)
+        # fleet runs the identical per-block executables as the vmap
+        # fallback, fleet means in their own program: bitwise equal.
+        for k in ("aopi", "acc", "q"):
+            np.testing.assert_array_equal(getattr(fleet, k)[p],
+                                          getattr(vmap_, k)[p],
+                                          err_msg=f"{p} {k}")
         # shard_map compiles a distinct num_partitions>1 XLA module; fp
         # rounding may flip knife-edge discrete allocations, so parity is
         # statistical: per-scenario horizon means.

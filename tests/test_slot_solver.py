@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from repro.core import allocate, aopi, baselines, bcd, lbcd, profiles
 from repro.kernels import slot_solver
@@ -184,7 +184,8 @@ def _config_inputs(n, seed=0, m=5, r=6):
     return b, c, acc, xi, size, eff
 
 
-@pytest.mark.parametrize("n,block_n", [(7, 1024), (40, 16), (64, 64)])
+@pytest.mark.parametrize("n,block_n", [(7, 1024), (1100, 1024),
+                                       (2048, 1024)])
 def test_config_argmin_matches_ref(n, block_n):
     """Streaming kernel == flat argmin (incl. non-divisible tiling)."""
     for seed in range(3):
@@ -239,32 +240,62 @@ def test_solve_slot_pallas_rejects_interior_point():
         bcd.solve_slot(*args, n_servers=3, solver_backend="cuda")
 
 
+def test_churn_mask_resolves_to_jnp_or_raises():
+    """The pallas kernels take no churn mask: "auto" resolves a masked
+    solve to jnp in ``resolve_spec`` (where the choice is visible), and an
+    explicit "pallas" request with a mask raises instead of silently
+    running jnp."""
+    n = bcd.AUTO_PALLAS_MIN_CAMERAS
+    assert bcd.resolve_spec("auto", n).backend == "pallas"
+    assert bcd.resolve_spec("auto", n, masked=True).backend == "jnp"
+    assert bcd.resolve_spec("pallas", n, masked=True).backend == "pallas"
+    args = _slot_instance(0, n=n)
+    active = jnp.ones((n,), jnp.float32).at[0].set(0.0)
+    with pytest.raises(ValueError, match="churn"):
+        bcd.solve_slot(*args, n_servers=3, solver_backend="pallas",
+                       active=active)
+    dec = bcd.solve_slot(*args, n_servers=3, solver_backend="auto",
+                         active=active)
+    ref = bcd.solve_slot(*args, n_servers=3, solver_backend="jnp",
+                         active=active)
+    np.testing.assert_array_equal(np.asarray(dec.b), np.asarray(ref.b))
+    assert float(dec.b[0]) == 0.0
+
+
 def test_rollout_backend_parity():
     """Whole-horizon scan (first-fit assignments traced through the
     layout build) agrees across backends.
 
-    Contract: per-slot parity is float32-tight *given the assignment*,
-    but the backends' different fp summation order can flip a knife-edge
-    first-fit tie into a different (equally valid) placement on rare
-    slots — same amplification the shard_map caveat documents. So slots
-    with identical assignments must match tightly, tie-flip slots must be
-    rare, and the fleet aggregate must agree closely either way."""
+    Contract: per-slot parity is float32-tight *given the assignment*
+    once the dual search has converged, but the backends' different fp
+    summation order can flip a knife-edge first-fit tie into a different
+    (equally valid) placement on rare slots — same amplification the
+    shard_map caveat documents. The default "fast" effort stops the
+    search early, so its per-camera allocations agree only to that
+    truncation (a few 1e-3 where the fp order differs); the tight
+    per-camera check runs at the converged "seed" effort. Tie-flip
+    slots must be rare, and the fleet aggregate must agree closely
+    either way."""
     sys = profiles.EdgeSystem(n_cameras=10, n_servers=3, n_slots=8,
                               mean_bandwidth_hz=15e6,
                               mean_compute_flops=20e12)
     tab = sys.horizon(8)
-    r_jnp = lbcd.rollout(tab, 10.0, 0.7)
-    r_pl = lbcd.rollout(tab, 10.0, 0.7, solver_backend="pallas")
-    same = np.all(np.asarray(r_jnp.assign) == np.asarray(r_pl.assign),
-                  axis=1)
-    assert same.mean() >= 0.75, f"tie flips on {(~same).sum()}/8 slots"
-    np.testing.assert_allclose(np.asarray(r_pl.aopi)[same],
-                               np.asarray(r_jnp.aopi)[same], rtol=1e-3)
-    np.testing.assert_allclose(np.asarray(r_pl.aopi).mean(axis=1),
-                               np.asarray(r_jnp.aopi).mean(axis=1),
-                               rtol=5e-3)
-    np.testing.assert_allclose(np.asarray(r_pl.q), np.asarray(r_jnp.q),
-                               rtol=1e-3, atol=1e-4)
+    for effort in ("seed", "fast"):
+        r_jnp = lbcd.rollout(tab, 10.0, 0.7, solver_effort=effort)
+        r_pl = lbcd.rollout(tab, 10.0, 0.7, solver_backend="pallas",
+                            solver_effort=effort)
+        same = np.all(np.asarray(r_jnp.assign) == np.asarray(r_pl.assign),
+                      axis=1)
+        assert same.mean() >= 0.75, f"tie flips on {(~same).sum()}/8 slots"
+        if effort == "seed":
+            np.testing.assert_allclose(np.asarray(r_pl.aopi)[same],
+                                       np.asarray(r_jnp.aopi)[same],
+                                       rtol=1e-4)
+        np.testing.assert_allclose(np.asarray(r_pl.aopi).mean(axis=1),
+                                   np.asarray(r_jnp.aopi).mean(axis=1),
+                                   rtol=5e-3)
+        np.testing.assert_allclose(np.asarray(r_pl.q), np.asarray(r_jnp.q),
+                                   rtol=1e-3, atol=1e-4)
 
 
 def test_sweep_threads_solver_backend():
@@ -641,7 +672,7 @@ def test_baseline_rollout_backend_parity():
 _STRUCTURAL_PRIMS = frozenset({
     "dynamic_slice", "slice", "squeeze", "reshape", "broadcast_in_dim",
     "transpose", "convert_element_type", "copy", "gather", "concatenate",
-    "pad", "pjit", "scan", "while", "cond", "closed_call", "pallas_call",
+    "pad", "jit", "scan", "while", "cond", "closed_call", "pallas_call",
     "custom_jvp_call", "custom_vjp_call_jaxpr",
 })
 

@@ -91,15 +91,14 @@ def test_quantization_error_bound():
 def test_compressed_psum_matches_mean():
     """shard_map compressed all-reduce ~= exact mean within int8 error."""
     import os
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     n = len(jax.devices())
     from repro.launch.mesh import make_mesh
     mesh = make_mesh((n,), ("d",))
     x = jax.random.normal(KEY, (n, 64))
 
-    f = shard_map(lambda v: compression.compressed_psum(v[0], "d")[None],
-                  mesh=mesh, in_specs=P("d"), out_specs=P("d"))
+    f = jax.shard_map(lambda v: compression.compressed_psum(v[0], "d")[None],
+                      mesh=mesh, in_specs=P("d"), out_specs=P("d"))
     out = np.asarray(f(x))
     expect = np.asarray(jnp.mean(x, 0))
     scale = np.abs(np.asarray(x)).max() / 127.0
