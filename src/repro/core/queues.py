@@ -471,8 +471,6 @@ def gi_g1_window(lam, mu, p, pol, *, seed: int = 0, t0: int = 0,
     if active is not None:
         live = live & (np.atleast_2d(np.asarray(active)) > 0.0)
     e, n = lam.shape
-    obs.histogram("queues.batch_elems",
-                  delay_model=delay_model).observe(e * n * n_frames)
     with obs.span("queues.gi_g1_window", delay_model=delay_model,
                   epochs=e, streams=n, n_frames=n_frames), jax.enable_x64(True):
         keys = jax.vmap(jax.random.fold_in, (None, 0))(
@@ -485,7 +483,11 @@ def gi_g1_window(lam, mu, p, pol, *, seed: int = 0, t0: int = 0,
             jnp.asarray(np.atleast_2d(np.asarray(pol, np.int32))),
             keys, float(horizon), n_frames, str(delay_model),
             int(collect_samples))
-        out = {k: np.asarray(v, np.float64) for k, v in out.items()}
+        with obs.span("data_plane.wait"):
+            jax.block_until_ready(out)
+        with obs.span("data_plane.fetch", leaves=len(out),
+                      bytes=sum(int(v.nbytes) for v in out.values())):
+            out = {k: np.asarray(v, np.float64) for k, v in out.items()}
         if not live.all():
             # Dead lanes ran on clamped stand-in rates — zero them out.
             samples = out.pop("delay_samples", None)

@@ -8,9 +8,11 @@ replanning fast), so the plan/measure/replan loop measures itself:
     ``delay_model``, ``solver_backend``), cheap enough to be on by
     default (:mod:`repro.obs.metrics`);
   * **traces** — nested wall-clock spans streaming to JSONL and
-    renderable as Chrome trace-event JSON for Perfetto, with
-    ``jax.named_scope``/``jax.profiler.TraceAnnotation`` entered inside
-    every span so device profiles line up (:mod:`repro.obs.trace`);
+    renderable as Chrome trace-event JSON for Perfetto, with a
+    ``jax.profiler.TraceAnnotation`` entered inside every span so a
+    profiler trace holds the same names on the device trace's clock
+    (:mod:`repro.obs.trace`), and one ``jax.compile`` event per XLA
+    compile, parented to the span open on the compiling thread;
   * **exporters** — Prometheus text exposition + JSONL + the
     ``python -m repro.obs.report <run_dir>`` dashboard
     (:mod:`repro.obs.export`, :mod:`repro.obs.report`).
@@ -37,6 +39,8 @@ from __future__ import annotations
 
 import atexit
 import os
+
+from jax import monitoring as _monitoring
 
 from . import export as _export
 from . import trace as _trace
@@ -176,6 +180,23 @@ def event(name: str, **attrs):
         return None
     _registry.counter(name + ".count", **_metric_labels(attrs)).inc()
     return _trace.record_event(name, _buffer, attrs)
+
+
+#: The duration ``jax.monitoring`` records around each backend compile
+#: (or persistent-cache load) of a jitted program.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_compile(jax_event: str, duration: float, **kwargs) -> None:
+    """Record each compile as a ``jax.compile`` event (and bump
+    ``jax.compile.count``): parented to the span open on the compiling
+    thread, so a trace shows which step compiled, and for how long."""
+    if jax_event == COMPILE_EVENT and _enabled:
+        event("jax.compile", seconds=float(duration),
+              fun=str(kwargs.get("fun_name", "?")))
+
+
+_monitoring.register_event_duration_secs_listener(_on_compile)
 
 
 def count_dispatch(name: str, **labels) -> None:

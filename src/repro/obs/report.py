@@ -13,7 +13,9 @@ plus ``metrics.jsonl``) and prints the service-latency story per
     stream (the counts come from the same instrumented code paths as
     ``AnalyticsService.early_replans``);
   * data-plane measurement throughput (``gi_g1_window`` dispatches) and
-    per-backend ``solve_slot`` dispatch timing.
+    per-backend ``solve_slot`` dispatch timing;
+  * XLA compiles (``jax.compile`` events): count and seconds, grouped by
+    the span that was open when each compiled.
 """
 from __future__ import annotations
 
@@ -28,6 +30,7 @@ PLAN_SPAN = "service.plan_window"
 MEASURE_SPAN = "service.measure_window"
 EPOCH_SPAN = "service.run_epoch"
 REPLAN_EVENT = "service.early_replan"
+COMPILE_EVENT = "jax.compile"
 
 
 def quantile(values: list[float], q: float) -> float:
@@ -80,6 +83,27 @@ def load_metrics(run_dir: str) -> list[dict]:
 def _group(ev: dict) -> tuple[str, str]:
     args = ev.get("args", {})
     return (str(args.get("policy", "?")), str(args.get("family", "?")))
+
+
+def compile_lines(events: list[dict]) -> list[str]:
+    """``jax.compile`` events: the count and seconds, then the same per
+    parent span name (``(none)`` outside any span, ``?`` where the
+    artifact kept no parent ids)."""
+    names = {ev["id"]: ev["name"] for ev in events if "id" in ev}
+    by_parent: dict[str, list[float]] = defaultdict(list)
+    for ev in events:
+        if ev["name"] == COMPILE_EVENT:
+            pid = ev.get("parent")
+            parent = ("?" if pid is None else
+                      "(none)" if pid == 0 else names.get(pid, "?"))
+            by_parent[parent].append(
+                float(ev.get("args", {}).get("seconds", 0.0)))
+    n = sum(len(v) for v in by_parent.values())
+    total = sum(sum(v) for v in by_parent.values())
+    lines = [f"compiles: {n}, {total:.3f}s"]
+    for parent, secs in sorted(by_parent.items(), key=lambda kv: -sum(kv[1])):
+        lines.append(f"  under {parent}: {len(secs)}, {sum(secs):.3f}s")
+    return lines
 
 
 def build_report(events: list[dict], metrics: list[dict]) -> str:
@@ -164,6 +188,7 @@ def build_report(events: list[dict], metrics: list[dict]) -> str:
             f"{m['labels'].get('entry', '?')}={m['value']:g}"
             for m in sorted(disp, key=lambda m: -m["value"])[:8])
         lines.append(f"kernel entry traces: {total:g} ({per})")
+    lines += compile_lines(events)
     return "\n".join(lines)
 
 

@@ -10,11 +10,13 @@ configured and are kept in a bounded in-memory buffer either way, from
 which :func:`chrome_trace` renders Chrome trace-event JSON (load it at
 ``ui.perfetto.dev``).
 
-Inside every span the code also enters ``jax.named_scope`` and
-``jax.profiler.TraceAnnotation`` with the span name, so a device profile
-captured with ``jax.profiler.trace`` lines up against the host spans —
-the host-side "plan_horizon took 40ms" and the device-side "which kernels
-those 40ms were" views share names.
+Inside every span the code also enters ``jax.profiler.TraceAnnotation``
+with the span name, so a profile captured with ``jax.profiler.trace``
+holds each span on its host plane, on the device trace's own clock: the
+host-side "plan_horizon took 40ms" and the device-side "which programs
+ran, and where the device sat idle, in those 40ms" views share names.
+Spans are opened around jitted calls, never inside a trace, so they name
+no ops of the lowered programs.
 
 Timebase: ``time.perf_counter()`` relative to module import (the
 ``ts``/``dur`` fields are seconds on one monotonic clock, directly
@@ -132,9 +134,8 @@ class TraceBuffer:
 class Span:
     """One wall-clock span; records an event on exit.
 
-    Use through :func:`repro.obs.span` — entering also opens
-    ``jax.named_scope``/``jax.profiler.TraceAnnotation`` so device
-    profiles carry the same names.
+    Use through :func:`repro.obs.span` — entering also opens a
+    ``jax.profiler.TraceAnnotation`` so profiles carry the same names.
     """
 
     __slots__ = ("name", "attrs", "buffer", "sid", "t0", "_cm", "_metric")
@@ -152,20 +153,19 @@ class Span:
     def __enter__(self) -> "Span":
         stack = self.buffer._stack()
         stack.append(self.sid)
-        self._cm = contextlib.ExitStack()
-        self._cm.enter_context(jax.named_scope(self.name))
-        self._cm.enter_context(jax.profiler.TraceAnnotation(self.name))
+        self._cm = jax.profiler.TraceAnnotation(self.name)
+        self._cm.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         # Exception-safe teardown: the span must pop off the per-thread
         # stack and record its event even when the body raised (or when
-        # closing the jax scopes raises) — otherwise one raise corrupts
+        # closing the annotation raises) — otherwise one raise corrupts
         # the span tree for everything recorded after it.
         t1 = time.perf_counter()
         try:
-            self._cm.close()
+            self._cm.__exit__(None, None, None)
         finally:
             stack = self.buffer._stack()
             if stack and stack[-1] == self.sid:

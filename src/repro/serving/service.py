@@ -422,11 +422,14 @@ class AnalyticsService:
         Pure lookahead: neither the controller's virtual queue nor the data
         plane advances; ``run_epoch`` commits epochs as they execute.
         """
-        tables = self._window_tables(t0, t0 + k)
+        with obs.span("planner.tables", k=k):
+            tables = self._window_tables(t0, t0 + k)
         ctrl = self.controller
-        if isinstance(ctrl, LBCDController):
-            return ctrl.plan(tables)
-        return ctrl._rollout(tables)
+        # The span times the enqueue: the device work runs on after it.
+        with obs.span("planner.dispatch", k=k):
+            if isinstance(ctrl, LBCDController):
+                return ctrl.plan(tables)
+            return ctrl._rollout(tables)
 
     def _slot_record(self, t: int) -> lbcd.SlotRecord:
         if self.planner != "scan":
@@ -477,7 +480,11 @@ class AnalyticsService:
         if kind == "solver_nonconverge":
             raise fault_plane.InjectedSolverFault("solver_nonconverge")
         start = time.perf_counter()
-        plan = jax.tree.map(np.asarray, self.plan_horizon(k, t))
+        plan = jax.block_until_ready(self.plan_horizon(k, t))
+        leaves, tree = jax.tree.flatten(plan)
+        with obs.span("planner.fetch", leaves=len(leaves),
+                      bytes=sum(int(x.nbytes) for x in leaves)):
+            plan = tree.unflatten([np.asarray(x) for x in leaves])
         elapsed = time.perf_counter() - start
         if kind == "solver_nan":
             plan = dataclasses.replace(
@@ -682,7 +689,9 @@ class AnalyticsService:
         res, t0 = self._plan, self._plan_t0
         n_epochs = int(res.q.shape[0])
         dec = res.decision
-        lam_true, p_true = self._plane_rates_window(t0, n_epochs, dec)
+        with obs.span("data_plane.inputs", epochs=n_epochs,
+                      streams=int(dec.lam.shape[-1])):
+            lam_true, p_true = self._plane_rates_window(t0, n_epochs, dec)
         with obs.span("service.measure_window", policy=self._policy,
                       delay_model=self._obs_model(), t0=t0,
                       epochs=n_epochs, streams=int(lam_true.shape[-1])):
@@ -708,7 +717,9 @@ class AnalyticsService:
             measured_w, tels = self._plan_meas
             j = t - self._plan_t0
             return measured_w[j], tels[j]
-        lam_true, p_true = self._plane_rates(t, dec)
+        with obs.span("data_plane.inputs", epochs=1,
+                      streams=int(np.shape(dec.lam)[-1])):
+            lam_true, p_true = self._plane_rates(t, dec)
         with obs.span("service.measure_window", policy=self._policy,
                       delay_model=self._obs_model(), t0=t, epochs=1,
                       streams=int(np.asarray(lam_true).shape[-1])):
@@ -861,8 +872,6 @@ class AnalyticsService:
         div = rep.measured_aopi / max(rep.predicted_aopi, 1e-12) - 1.0
         self.divergences.append(div)
         obs.gauge("service.divergence", policy=self._policy).set(div)
-        obs.histogram("service.divergence.abs",
-                      policy=self._policy).observe(abs(div))
         obs.counter("service.epochs", policy=self._policy).inc()
         self._maybe_replan(t, div)
         return rep
