@@ -278,6 +278,15 @@ def frames_budget(max_lam: float, horizon: float, frames_cap: int,
 #: a pure function of the frame budget.
 F32_MAX_FRAMES = 1024
 
+#: Frames per iteration of the frame scan. Each iteration takes one
+#: ``[FRAME_BLOCK, E*N]`` block of every per-frame input and runs the
+#: frame step on its rows in order, so the per-iteration input slicing
+#: and loop overhead are paid once a block, not once a frame. The
+#: ``n_frames % FRAME_BLOCK`` frames left over run one by one after it.
+#: Larger blocks run the scan a little faster but compile, lower and load
+#: from the compile cache a larger program for every frame budget.
+FRAME_BLOCK = 16
+
 
 def _n_uniforms(delay_model: str) -> int:
     """Uniform variates consumed per frame: T + O + the accuracy coin.
@@ -324,18 +333,80 @@ def _delays_from_uniforms(u, mean, delay_model: str):
 SAMPLE_STREAM_CAP = 32
 
 
+@jax.jit
+def _frame_step(carry, xs, *, is_lcfsp, h_eff, zero, p):
+    """One frame of every (epoch, stream) lane: both queue recurrences and
+    the exact age integral, advanced by one arrival.
+
+    Jitted so that a block's ``FRAME_BLOCK`` steps are one traced and
+    lowered function called ``FRAME_BLOCK`` times (XLA inlines the calls),
+    not ``FRAME_BLOCK`` copies of its operations to trace and lower."""
+    a, s, m, last_t, age0, area, n_arr, n_done, n_acc = carry
+    # A frame's row of each input: [E*N], or [1, E*N] from a block.
+    t_f, t_nxt, o_f, u_f = (x.reshape(zero.shape) for x in xs)
+    a = a + t_f                            # arrival a_i = tau_{i+1}
+    gen = a - t_f                          # generation tau_i
+    s = s + o_f                            # cumsum of service times
+    m = jnp.maximum(m, a - (s - o_f))      # running max idle slack
+    finish = jnp.where(is_lcfsp, a + o_f, s + m)
+    completed = jnp.where(is_lcfsp, o_f < t_nxt, True)
+    done = completed & (finish <= h_eff)
+    valid = done & (u_f < p)
+    # Age resets to finish - gen at each valid event; events are
+    # nondecreasing in time, so accumulate the closed segment.
+    seg = jnp.where(valid, finish - last_t, zero)
+    area = area + age0 * seg + 0.5 * seg * seg
+    last_t = jnp.where(valid, finish, last_t)
+    age0 = jnp.where(valid, finish - gen, age0)
+    n_arr = n_arr + (a <= h_eff)
+    n_done = n_done + done
+    n_acc = n_acc + valid
+    return (a, s, m, last_t, age0, area, n_arr, n_done, n_acc)
+
+
+def _scan_frames(step, carry, xs):
+    """Apply ``step(carry, rows) -> carry`` to every frame of the
+    ``[F, ...]`` inputs ``xs``, in frame order; ``rows`` holds the frame's
+    row of each input, shaped ``[...]`` in the tail, ``[1, ...]`` in a
+    block.
+
+    A ``lax.scan`` over blocks of ``FRAME_BLOCK`` frames (each iteration
+    splits its block into rows and steps through them), then a plain
+    per-frame scan over the ``F % FRAME_BLOCK`` frames left over (the
+    tail). Either scan is left out where it has no frames.
+    """
+    n_frames = xs[0].shape[0]
+    n_blocks = n_frames // FRAME_BLOCK
+    cut = n_blocks * FRAME_BLOCK
+    if n_blocks:
+        def block(c, xb):
+            for row in zip(*(lax.split(x, (1,) * FRAME_BLOCK) for x in xb)):
+                c = step(c, row)
+            return c, None
+        carry, _ = lax.scan(block, carry, tuple(
+            x[:cut].reshape(n_blocks, FRAME_BLOCK, *x.shape[1:])
+            for x in xs))
+    if cut < n_frames:
+        carry, _ = lax.scan(lambda c, x: (step(c, x), None), carry,
+                            tuple(x[cut:] for x in xs))
+    return carry
+
+
 @functools.partial(jax.jit, static_argnames=(
     "n_frames", "delay_model", "collect_samples"))
 def _window_sim(lam, mu, p, pol, keys, horizon, n_frames: int,
                 delay_model: str, collect_samples: int = 0):
-    """The fused data-plane program: ONE ``lax.scan`` over the frame axis
-    with ``[E * N]``-wide vector carries.
+    """The fused data-plane program: one sequential pass over the frame
+    axis with ``[E * N]``-wide vector carries, run as a ``lax.scan`` over
+    blocks of ``FRAME_BLOCK`` frames plus a per-frame scan over the
+    ``n_frames % FRAME_BLOCK`` left over (``_scan_frames``). The frames
+    are stepped in the same order, with the same arithmetic, either way.
 
     Single-pass recurrences (like the numpy oracle's cumsums, unlike
     XLA's O(n log n) associative cumulative ops) batched across every
     (epoch, stream) pair of the window, with the exact piecewise-linear
     age integral accumulated forward in the same pass — so the whole
-    window is one dispatch whose per-step body is a handful of fused
+    window is one dispatch whose per-frame step is a handful of fused
     elementwise ops on the flattened stream vector.
     """
     e, n = lam.shape
@@ -371,31 +442,11 @@ def _window_sim(lam, mu, p, pol, keys, horizon, n_frames: int,
     h_eff = jnp.minimum(jnp.asarray(horizon, dtype), T.sum(axis=0))
     zero = jnp.zeros(e * n, dtype)
 
-    def step(carry, xs):
-        a, s, m, last_t, age0, area, n_arr, n_done, n_acc = carry
-        t_f, t_nxt, o_f, u_f = xs
-        a = a + t_f                            # arrival a_i = tau_{i+1}
-        gen = a - t_f                          # generation tau_i
-        s = s + o_f                            # cumsum of service times
-        m = jnp.maximum(m, a - (s - o_f))      # running max idle slack
-        finish = jnp.where(is_lcfsp, a + o_f, s + m)
-        completed = jnp.where(is_lcfsp, o_f < t_nxt, True)
-        done = completed & (finish <= h_eff)
-        valid = done & (u_f < p)
-        # Age resets to finish - gen at each valid event; events are
-        # nondecreasing in time, so accumulate the closed segment.
-        seg = jnp.where(valid, finish - last_t, zero)
-        area = area + age0 * seg + 0.5 * seg * seg
-        last_t = jnp.where(valid, finish, last_t)
-        age0 = jnp.where(valid, finish - gen, age0)
-        n_arr = n_arr + (a <= h_eff)
-        n_done = n_done + done
-        n_acc = n_acc + valid
-        return (a, s, m, last_t, age0, area, n_arr, n_done, n_acc), None
-
+    step = functools.partial(_frame_step, is_lcfsp=is_lcfsp, h_eff=h_eff,
+                             zero=zero, p=p)
     init = (zero, zero, jnp.full(e * n, -jnp.inf, dtype), zero, zero,
             zero, zero, zero, zero)
-    (a, s, m, last_t, age0, area, n_arr, n_done, n_acc), _ = lax.scan(
+    (a, s, m, last_t, age0, area, n_arr, n_done, n_acc) = _scan_frames(
         step, init, (T, T_next, O, coin))
     # Final open segment up to the effective horizon.
     seg = jnp.maximum(h_eff - last_t, zero)
@@ -448,9 +499,12 @@ def gi_g1_window(lam, mu, p, pol, *, seed: int = 0, t0: int = 0,
     are back-to-back) for the telemetry-fitted :func:`fit_delay_model`
     selector. Dead-lane samples are zeroed.
 
-    One ``lax.scan`` over the frame axis carries every (epoch, stream)
+    One sequential pass over the frame axis carries every (epoch, stream)
     recurrence as an ``[E*N]`` vector — single-pass like the numpy
-    oracle's cumsums, but batched across the whole window. Short frame
+    oracle's cumsums, but batched across the whole window. The pass is a
+    ``lax.scan`` over blocks of ``FRAME_BLOCK`` frames, then a per-frame
+    scan over the ``n_frames % FRAME_BLOCK`` left over, in frame order
+    (the span's ``block`` and ``tail`` attributes). Short frame
     budgets (<= ``F32_MAX_FRAMES``) run in float32; longer horizons
     switch to float64 (scoped ``jax.enable_x64``) so multi-hour epochs keep
     sub-millisecond age resolution, matching the oracle. Returns host
@@ -472,7 +526,8 @@ def gi_g1_window(lam, mu, p, pol, *, seed: int = 0, t0: int = 0,
         live = live & (np.atleast_2d(np.asarray(active)) > 0.0)
     e, n = lam.shape
     with obs.span("queues.gi_g1_window", delay_model=delay_model,
-                  epochs=e, streams=n, n_frames=n_frames), jax.enable_x64(True):
+                  epochs=e, streams=n, n_frames=n_frames, block=FRAME_BLOCK,
+                  tail=n_frames % FRAME_BLOCK), jax.enable_x64(True):
         keys = jax.vmap(jax.random.fold_in, (None, 0))(
             jax.random.key(int(seed)), jnp.arange(t0, t0 + e))
         out = _window_sim(

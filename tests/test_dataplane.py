@@ -1,9 +1,20 @@
 """Batched device-resident GI/G/1 data plane (``queues.gi_g1_window`` /
 ``service.measure_window``): parity with the numpy oracle and Theorems 1-2,
-collision-free key streams, epoch-horizon truncation, and determinism."""
+collision-free key streams, epoch-horizon truncation, determinism, and
+the blocked frame scan."""
+import functools
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
+from repro import obs
 from repro.core import aopi, queues
 from repro.serving import service
 
@@ -323,3 +334,171 @@ def test_unknown_delay_model_raises():
         service.measure_mm1_loop(
             np.ones(1), np.ones(1), np.ones(1) * 0.5, np.zeros(1),
             delay_model="pareto")
+
+
+# ---------------------------------------------------------------------------
+# Blocked frame scan: FRAME_BLOCK frames per scan iteration, then a tail
+# ---------------------------------------------------------------------------
+
+FB = queues.FRAME_BLOCK
+
+
+def _per_frame_window_sim(monkeypatch, *args):
+    """``_window_sim`` with no block scan: every frame in the tail, i.e.
+    one plain per-frame ``lax.scan``. A fresh jit of a fresh function,
+    so it shares no trace with ``queues._window_sim``."""
+    def per_frame(*a):
+        return queues._window_sim.__wrapped__(*a)
+    with monkeypatch.context() as m:
+        m.setattr(queues, "FRAME_BLOCK", 1 << 40)
+        return jax.jit(per_frame, static_argnums=(6, 7, 8))(*args)
+
+
+@pytest.mark.parametrize("delay_model", ["mm1", "weibull"])
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+@pytest.mark.parametrize("n_frames", [4 * FB, 3 * FB + 5, FB - 7],
+                         ids=["blocks", "blocks+tail", "tail"])
+def test_blocked_frame_scan_is_bitwise_the_per_frame_scan(
+        n_frames, dtype, delay_model, monkeypatch):
+    """The block scan plus tail steps every frame in order with the same
+    arithmetic: its carries equal a plain per-frame ``lax.scan`` of
+    ``_frame_step`` bit for bit, and so do ``_window_sim``'s outputs,
+    on lanes of both policies, with the horizon cutting some lanes and
+    the frame budget others."""
+    rng = np.random.default_rng(n_frames)
+    e, n = 2, 6
+    lam = rng.uniform(2.0, 8.0, (e, n))
+    mu = rng.uniform(4.0, 12.0, (e, n))
+    p = rng.uniform(0.3, 1.0, (e, n))
+    pol = np.tile([0, 1], (e, n // 2))
+    horizon = 0.25 * n_frames
+    with jax.enable_x64(True):
+        lanes = e * n
+        u = jnp.asarray(rng.uniform(size=(3, n_frames, lanes)), dtype)
+        T = queues._delays_from_uniforms(
+            u[:1], jnp.asarray(1.0 / lam.ravel(), dtype), delay_model)
+        O = queues._delays_from_uniforms(
+            u[1:2], jnp.asarray(1.0 / mu.ravel(), dtype), delay_model)
+        T_next = jnp.concatenate([T[1:], jnp.full((1, lanes), jnp.inf, dtype)])
+        xs = (T, T_next, O, u[2])
+        zero = jnp.zeros(lanes, dtype)
+        step = functools.partial(
+            queues._frame_step, is_lcfsp=jnp.asarray(pol.ravel() == 1),
+            h_eff=jnp.minimum(jnp.asarray(horizon, dtype), T.sum(axis=0)),
+            zero=zero, p=jnp.asarray(p.ravel(), dtype))
+        init = (zero, zero, jnp.full(lanes, -jnp.inf, dtype)) + (zero,) * 6
+        blocked = jax.jit(lambda c, x: queues._scan_frames(step, c, x))(
+            init, xs)
+        plain = jax.jit(lambda c, x: lax.scan(
+            lambda ci, xi: (step(ci, xi), None), c, x)[0])(init, xs)
+        for got, want in zip(blocked, plain):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+        keys = jax.vmap(jax.random.fold_in, (None, 0))(
+            jax.random.key(n_frames), jnp.arange(e))
+        args = (jnp.asarray(lam, dtype), jnp.asarray(mu, dtype),
+                jnp.asarray(p, dtype), jnp.asarray(pol, jnp.int32), keys,
+                horizon, n_frames, delay_model, 8)
+        got = queues._window_sim(*args)
+        want = _per_frame_window_sim(monkeypatch, *args)
+    assert sorted(got) == sorted(want)
+    assert got["delay_samples"].shape == (e, n, min(8, n_frames))
+    for k in got:
+        assert got[k].dtype == jnp.dtype(dtype)
+        np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want[k]))
+
+
+def test_one_block_and_a_tail_differ_only_by_cpu_fma_contraction():
+    """With one block and a short tail (FRAME_BLOCK + 4 or + 8 frames),
+    XLA:CPU fuses the program differently from the per-frame scan and
+    contracts a multiply-add into an FMA there, moving ``aopi`` by 1-2
+    ulp. With the CPU's instruction set capped below FMA, in a process of
+    its own, both dtypes are bitwise equal again: the blocked scan does
+    the same arithmetic in the same order."""
+    code = textwrap.dedent(f"""
+        import os, sys
+        os.environ["XLA_FLAGS"] = "--xla_cpu_max_isa=SSE4_2"
+        sys.path.insert(0, {str(Path(queues.__file__).parents[2])!r})
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.core import queues
+
+        def per_frame(*a):
+            return queues._window_sim.__wrapped__(*a)
+
+        rng = np.random.default_rng(0)
+        e, n = 8, 30
+        ins = [rng.uniform(lo, hi, (e, n)) for lo, hi in
+               ((0.5, 2.0), (2.0, 4.0), (0.3, 1.0))]
+        pol = jnp.asarray(rng.integers(0, 2, (e, n)), jnp.int32)
+        fb = queues.FRAME_BLOCK
+        with jax.enable_x64(True):
+            keys = jax.vmap(jax.random.fold_in, (None, 0))(
+                jax.random.key(3), jnp.arange(e))
+            for dtype in ("float32", "float64"):
+                for f in (fb + 4, fb + 8):
+                    args = (*(jnp.asarray(x, dtype) for x in ins), pol,
+                            keys, 0.5 * f, f, "mm1", 0)
+                    got = queues._window_sim(*args)
+                    queues.FRAME_BLOCK = 1 << 40
+                    want = jax.jit(per_frame, static_argnums=(6, 7, 8))(*args)
+                    queues.FRAME_BLOCK = fb
+                    for k in got:
+                        assert np.array_equal(got[k], want[k]), (dtype, f, k)
+        print("bitwise")
+    """)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert out.stdout.split() == ["bitwise"]
+
+
+def _scans(jaxpr):
+    """Every ``scan`` eqn in ``jaxpr`` and in the jaxprs it calls."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            out.append(eqn)
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    out += _scans(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    out += _scans(sub)
+    return out
+
+
+@pytest.mark.parametrize("n_frames", [32768, 20480 + 12, FB - 7])
+def test_window_sim_scans_blocks_then_one_tail(n_frames):
+    """The serve cell's largest budget is whole blocks, with no tail
+    scan; a budget with a remainder adds exactly one per-frame scan of
+    it; a budget under one block is the tail alone. The span of each
+    call says so."""
+    e, n = 8, 30
+    with jax.enable_x64(True):
+        x = jnp.ones((e, n), jnp.float64)
+        keys = jax.vmap(jax.random.fold_in, (None, 0))(
+            jax.random.key(0), jnp.arange(e))
+        jaxpr = jax.make_jaxpr(queues._window_sim, static_argnums=(6, 7, 8))(
+            x, x, x, jnp.zeros((e, n), jnp.int32), keys, 300.0, n_frames,
+            "mm1", 0)
+    blocks, tail = divmod(n_frames, FB)
+    scans = _scans(jaxpr.jaxpr)
+    assert [s.params["length"] for s in scans] == \
+        [blocks] * (blocks > 0) + [tail] * (tail > 0)
+    if n_frames == 32768:
+        assert scans[0].params["length"] == 32768 // FB
+        # Each iteration takes one [FRAME_BLOCK, E*N] block of T, T_next,
+        # O and the coin.
+        xs = scans[0].params["jaxpr"].in_avals[-4:]
+        assert [a.shape for a in xs] == [(FB, e * n)] * 4
+
+    obs.reset()
+    try:
+        queues.gi_g1_window([2.0], [5.0], [0.9], [1], n_frames=n_frames,
+                            horizon=50.0)
+        (span,) = [ev["args"] for ev in obs.events()
+                   if ev["name"] == "queues.gi_g1_window"]
+    finally:
+        obs.reset()
+    assert (span["block"], span["tail"], span["n_frames"]) == \
+        (FB, tail, n_frames)
