@@ -31,11 +31,15 @@ from . import allocate, aopi
 from .. import obs
 from ..kernels import slot_solver
 
-# Fleet size at which the pallas kernels start winning. Below one 128-lane
-# tile the kernels pad every camera vector up to 128 lanes and lose to the
-# plain jnp path (BENCH_slot_solver.json: N=30 is 0.4-0.7x, N=300 is
-# 1.2-1.6x), so ``solver_backend="auto"`` stays on jnp under this threshold
-# — everywhere the flag goes, including the grid/scenario vmap paths.
+# Fleet size from which ``solver_backend="auto"`` plans with the pallas
+# kernels, everywhere the flag goes, including the grid/scenario vmap
+# paths; below one 128-lane tile the kernels pad every camera vector up to
+# 128 lanes. Measured on one TPU v5e (``plan_horizon(8)`` back to back,
+# every leaf copied to the host; p95 of a plan): at 30 cameras on 3
+# servers jnp 23.7-24.5 ms, pallas 16.0 ms; at 816 cameras on 125 servers
+# pallas 95.2-100.3 ms, jnp 129.9 ms. The pallas path is the faster at
+# both sizes there; this value, set from CPU-interpreter timings, has yet
+# to be re-derived on the chip.
 AUTO_PALLAS_MIN_CAMERAS = 128
 
 # Fleet size at which "auto" switches the water-fills to the camera-tiled
@@ -57,6 +61,13 @@ class SolverSpec:
     backend: str              # "jnp" | "pallas" | "auto" (pre-resolution)
     tile_n: int | None = None  # water-fill camera tile (None = untiled)
     fuse: bool = True          # one fused kernel for both water-fills
+
+    def __str__(self) -> str:
+        """The spec string :func:`parse_backend` reads back."""
+        return ":".join([self.backend]
+                        + ([] if self.tile_n is None
+                           else [f"tile={self.tile_n}"])
+                        + ([] if self.fuse else ["nofuse"]))
 
 
 def parse_backend(solver_backend) -> SolverSpec:

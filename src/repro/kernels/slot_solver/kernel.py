@@ -164,6 +164,7 @@ def config_argmin(b, c, acc, xi, size, eff, q, v, *, n_total: int,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((3, rows, LANES), jnp.int32),
         interpret=interpret,
+        name="slot_solver.config_argmin",
     )(qv, xi, size.reshape(1, n_r), b, c, eff, acc)
 
 
@@ -302,6 +303,7 @@ def waterfill(scale, p, pol, other, lo, hi, cf, member, *, mode: str,
         out_shape=jax.ShapeDtypeStruct((1, cap), jnp.float32),
         compiler_params=_VMEM_PARAMS,
         interpret=interpret,
+        name="slot_solver.waterfill",
     )(*vecs, member).reshape(cap)
 
 
@@ -375,6 +377,7 @@ def waterfill_pair(scale_b, p, pol, mu, lo_b, hi_b, cf_b, mu_scale, member,
         out_shape=[jax.ShapeDtypeStruct((1, cap), jnp.float32)] * 2,
         compiler_params=_VMEM_PARAMS,
         interpret=interpret,
+        name="slot_solver.waterfill_pair",
     )(mg, *vecs, member)
     return u.reshape(cap), v.reshape(cap)
 
@@ -584,6 +587,7 @@ def waterfill_tiled(block, *, mode: str, n_servers: int, tile: int,
                                         jnp.float32)],
         compiler_params=_VMEM_PARAMS,
         interpret=interpret,
+        name="slot_solver.waterfill_tiled",
     )(block)
     return x[0]
 
@@ -663,4 +667,5 @@ def baseline_argmax(b, c, acc, xi, size, eff, *, mode: str, threshold,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((2, rows, LANES), jnp.int32),
         interpret=interpret,
+        name="slot_solver.baseline_argmax",
     )(th, xi, size.reshape(1, n_r), b, c, eff, acc)

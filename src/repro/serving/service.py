@@ -51,7 +51,7 @@ import numpy as np
 
 from .. import faults as fault_plane
 from .. import obs
-from ..core import baselines, binpack, lbcd, queues
+from ..core import baselines, bcd, binpack, lbcd, queues
 from ..core.lbcd import LBCDController
 from ..core.profiles import HorizonTables
 from .scheduler import AoPITracker, Frame, StreamQueue, StreamTelemetry
@@ -75,6 +75,18 @@ _LADDER_FAILURES = (fault_plane.InjectedSolverFault, FloatingPointError,
 #: dispatch; larger windows are chunked along the epoch axis so peak
 #: device memory stays bounded (~a few hundred MB of f64 intermediates).
 MAX_BATCH_ELEMS = 1 << 25
+
+
+def _window_dispatch(lam, horizon: float, frames_cap: int,
+                     frames_floor: int = 200) -> tuple[int, int]:
+    """``(n_frames, epochs per dispatch)`` of :func:`measure_window` for
+    ``[E, N]`` arrival rates ``lam``: the fastest stream's frame budget,
+    and as many epochs a dispatch as ``MAX_BATCH_ELEMS`` holds."""
+    lam = np.atleast_2d(lam)
+    n_frames = queues.frames_budget(max(lam.max(), 1e-6), horizon,
+                                    frames_cap, frames_floor)
+    n = lam.shape[1]
+    return n_frames, max(int(MAX_BATCH_ELEMS // max(n * n_frames, 1)), 1)
 
 
 def measure_window(lam, mu, p, pol, *, epoch_duration: float = 300.0,
@@ -103,9 +115,8 @@ def measure_window(lam, mu, p, pol, *, epoch_duration: float = 300.0,
     pol = np.atleast_2d(np.asarray(pol))
     n_epochs, n = lam.shape
     horizon = float(epoch_duration)
-    n_frames = queues.frames_budget(max(lam.max(), 1e-6), horizon,
-                                    frames_cap, frames_floor)
-    e_chunk = max(int(MAX_BATCH_ELEMS // max(n * n_frames, 1)), 1)
+    n_frames, e_chunk = _window_dispatch(lam, horizon, frames_cap,
+                                         frames_floor)
     measured = np.zeros((n_epochs, n))
     tels: list[StreamTelemetry] = []
     for e0 in range(0, n_epochs, e_chunk):
@@ -426,10 +437,26 @@ class AnalyticsService:
             tables = self._window_tables(t0, t0 + k)
         ctrl = self.controller
         # The span times the enqueue: the device work runs on after it.
-        with obs.span("planner.dispatch", k=k):
+        with obs.span("planner.dispatch", k=k, **self._solver_attrs(tables)):
             if isinstance(ctrl, LBCDController):
                 return ctrl.plan(tables)
             return ctrl._rollout(tables)
+
+    def _solver_attrs(self, tables: HorizonTables) -> dict:
+        """The ``planner.dispatch`` span's fleet size and the slot solver
+        it resolves to there (``bcd.resolve_spec``)."""
+        if not obs.enabled():
+            return {}
+        ctrl = self.controller
+        backend = getattr(ctrl, "solver_backend", None)
+        if backend is None:           # MIN keeps its solver options apart
+            backend = getattr(ctrl, "kw", {}).get("solver_backend", "jnp")
+        n = int(tables.acc.shape[1])
+        spec = bcd.resolve_spec(backend, n,
+                                method=getattr(ctrl, "method", "waterfill"),
+                                masked=tables.active is not None)
+        return {"backend": str(spec), "n_cameras": n,
+                "n_servers": int(tables.budgets_b.shape[1])}
 
     def _slot_record(self, t: int) -> lbcd.SlotRecord:
         if self.planner != "scan":
@@ -690,8 +717,12 @@ class AnalyticsService:
         n_epochs = int(res.q.shape[0])
         dec = res.decision
         with obs.span("data_plane.inputs", epochs=n_epochs,
-                      streams=int(dec.lam.shape[-1])):
+                      streams=int(dec.lam.shape[-1])) as inputs:
             lam_true, p_true = self._plane_rates_window(t0, n_epochs, dec)
+            if obs.enabled():
+                _, e_chunk = _window_dispatch(lam_true, self.epoch_duration,
+                                              self.frames_cap)
+                inputs.set(dispatches=-(-n_epochs // e_chunk))
         with obs.span("service.measure_window", policy=self._policy,
                       delay_model=self._obs_model(), t0=t0,
                       epochs=n_epochs, streams=int(lam_true.shape[-1])):
@@ -718,7 +749,7 @@ class AnalyticsService:
             j = t - self._plan_t0
             return measured_w[j], tels[j]
         with obs.span("data_plane.inputs", epochs=1,
-                      streams=int(np.shape(dec.lam)[-1])):
+                      streams=int(np.shape(dec.lam)[-1]), dispatches=1):
             lam_true, p_true = self._plane_rates(t, dec)
         with obs.span("service.measure_window", policy=self._policy,
                       delay_model=self._obs_model(), t0=t, epochs=1,

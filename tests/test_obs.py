@@ -432,6 +432,43 @@ def test_fetch_spans_count_the_leaves_and_bytes_copied(served):
         assert e["args"] == {"leaves": 5, "bytes": 5 * 2 * 4 * 8}
 
 
+def _dispatches_per_window(events):
+    """``(data_plane.inputs dispatches, queues.gi_g1_window calls)`` of
+    each epoch that measured a plan window."""
+    by_id = _by_id(events)
+    out = []
+    for e in events:
+        if e["name"] != "data_plane.inputs":
+            continue
+        calls = sum(1 for g in events if g["name"] == "queues.gi_g1_window"
+                    and by_id[g["parent"]]["parent"] == e["parent"])
+        out.append((e["args"]["dispatches"], calls))
+    return out
+
+
+def test_dispatch_spans_name_the_fleet_and_its_resolved_solver(served):
+    _, events = served
+    dispatch = [e for e in events if e["name"] == "planner.dispatch"]
+    assert len(dispatch) == 2
+    for e in dispatch:
+        assert e["args"] == {"k": 2, "backend": "jnp", "n_cameras": 4,
+                             "n_servers": 2}
+
+
+def test_inputs_spans_count_the_data_plane_dispatches(served):
+    _, events = served
+    assert _dispatches_per_window(events) == [(1, 1), (1, 1)]
+
+
+def test_inputs_spans_count_a_window_split_into_dispatches(monkeypatch):
+    from repro.serving import service
+    # Room for one epoch of 4 streams at the 2000-frame cap: each plan
+    # window of 2 epochs takes two dispatches.
+    monkeypatch.setattr(service, "MAX_BATCH_ELEMS", 4 * 2000)
+    _serve_two_plan_windows()
+    assert _dispatches_per_window(obs.events()) == [(2, 2), (2, 2)]
+
+
 def test_phase_spans_are_not_recorded_when_disabled():
     obs.configure(enabled=False)
     _serve_two_plan_windows()

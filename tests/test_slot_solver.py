@@ -502,6 +502,15 @@ def test_parse_backend_knobs():
         bcd.parse_backend("cuda:tile=2")
 
 
+@pytest.mark.parametrize("spec", ["jnp", "pallas", "auto:nofuse",
+                                  "pallas:tile=4096",
+                                  "pallas:tile=2048:nofuse"])
+def test_spec_string_reads_back(spec):
+    parsed = bcd.parse_backend(spec)
+    assert str(parsed) == spec
+    assert bcd.parse_backend(str(parsed)) == parsed
+
+
 def test_resolve_spec_tile_policy():
     thr = bcd.AUTO_TILE_MIN_CAMERAS
     # Auto-tiling engages at the measured streaming-win threshold.
