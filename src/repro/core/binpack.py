@@ -5,19 +5,27 @@ are sized by Eq. (56), servers by Eq. (57), both sorted descending, and each
 camera goes to the first server with enough remaining bandwidth AND compute;
 if none fits, to the server with most remaining volume (lines 4-9).
 
-Two implementations, semantically equivalent (asserted in tests):
+Three implementations, semantically equivalent (asserted in tests):
 
   * ``first_fit``     — host-side numpy reference; O(N S) with tiny
     constants, used by the legacy per-slot controller path;
   * ``first_fit_jax`` — jit-safe (sort + ``fori_loop``) variant traced
     inside the ``lax.scan`` rollout engine so whole-horizon runs never
-    leave the device.
+    leave the device;
+  * ``first_fit_jax(..., backend="pallas")`` — the same sorts, with the
+    placement loop run as one Pallas kernel
+    (``kernels.slot_solver.first_fit``) that keeps the remaining
+    capacities in vector registers; the rollout takes it where the slot
+    solver resolves to pallas (``bcd.resolve_spec``). Placements equal
+    the ``fori_loop``'s exactly: same f32 operations, same tie-breaks.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..kernels import slot_solver
 
 
 def first_fit(b_hat: np.ndarray, c_hat: np.ndarray, budgets_b: np.ndarray,
@@ -62,13 +70,17 @@ def first_fit(b_hat: np.ndarray, c_hat: np.ndarray, budgets_b: np.ndarray,
 
 
 def first_fit_jax(b_hat: jnp.ndarray, c_hat: jnp.ndarray,
-                  budgets_b: jnp.ndarray,
-                  budgets_c: jnp.ndarray) -> jnp.ndarray:
+                  budgets_b: jnp.ndarray, budgets_c: jnp.ndarray,
+                  backend: str = "jnp",
+                  interpret: bool | None = None) -> jnp.ndarray:
     """Jit-safe Algorithm 2 placement, equivalent to ``first_fit``.
 
     Cameras/servers are sorted by the Eq. (56)/(57) volumes, then a
     ``fori_loop`` places one camera per iteration (vectorized over servers).
-    Traceable under jit/vmap/scan; returns int32[N] server ids.
+    ``backend="pallas"`` runs that loop as one kernel
+    (``slot_solver.first_fit``; ``interpret`` as for the other slot-solver
+    kernels) and returns the same assignment. Traceable under
+    jit/vmap/scan; returns int32[N] server ids.
     """
     b_hat = jnp.asarray(b_hat)
     c_hat = jnp.asarray(c_hat)
@@ -81,6 +93,16 @@ def first_fit_jax(b_hat: jnp.ndarray, c_hat: jnp.ndarray,
     psi = budgets_b / tot_b + budgets_c / tot_c          # Eq. (57)
     cam_order = jnp.argsort(-phi)                        # largest first
     srv_order = jnp.argsort(-psi)
+    if backend == "pallas":
+        pos = slot_solver.first_fit(
+            b_hat[cam_order], c_hat[cam_order], budgets_b[srv_order],
+            budgets_c[srv_order], srv_order, tot_b, tot_c,
+            interpret=interpret)
+        return jnp.zeros(b_hat.shape[0], jnp.int32).at[cam_order].set(
+            srv_order[pos].astype(jnp.int32))
+    if backend != "jnp":
+        raise ValueError(f"unknown first-fit backend {backend!r};"
+                         " known: ('jnp', 'pallas')")
 
     def body(i, state):
         rem_b, rem_c, assign = state

@@ -149,6 +149,9 @@ def rollout(tables: HorizonTables, v, p_min, q0=0.0,
     # ``tables.active is None`` is a static (trace-time) branch: the
     # maskless program below is byte-identical to the pre-churn engine.
     has_active = tables.active is not None
+    # First-fit runs as a kernel where the slot solver does.
+    first_fit = bcd.resolve_spec(solver_backend, n, method=method,
+                                 masked=has_active).backend
 
     def step(q, xs):
         if has_active:
@@ -161,7 +164,9 @@ def rollout(tables: HorizonTables, v, p_min, q0=0.0,
                      jnp.sum(bb)[None], jnp.sum(bc)[None], q, v, n_servers=1,
                      active=act_t)
         # Algorithm 2 lines 3-9: first-fit placement (jit-safe).
-        assign = binpack.first_fit_jax(virt.b, virt.c, bb, bc)
+        assign = binpack.first_fit_jax(virt.b, virt.c, bb, bc,
+                                       backend=first_fit,
+                                       interpret=interpret)
         # Algorithm 2 line 10: re-solve per real server.
         dec = solve(acc_t, tables.xi, tables.size, eff_t, assign,
                     bb, bc, q, v, n_servers=n_servers, active=act_t)

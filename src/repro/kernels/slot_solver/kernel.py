@@ -1,6 +1,6 @@
-"""Pallas TPU kernels for the Algorithm-1 slot-solver hot path.
+"""Pallas TPU kernels for the slot-solver hot path (Algorithms 1 and 2).
 
-Four kernels, all pure VPU work (no MXU):
+Six kernels, all pure VPU work (no MXU):
 
   * ``config_argmin`` — Algorithm 1 line 3. The jnp backend materializes the
     ``[N, M, R, 2]`` FCFS/LCFSP score tensor in HBM once per BCD pass (and
@@ -52,6 +52,18 @@ Four kernels, all pure VPU work (no MXU):
     ``[N, M, R]`` score/latency tensors are never materialized. The
     elementwise score math matches the jnp references operation for
     operation, so the returned indices are bitwise identical.
+
+  * ``first_fit`` — Algorithm 2 lines 3-9, the first-fit server
+    selection. The placement is one chain of dependent steps (each camera
+    sees the capacities its predecessors left), so it cannot be batched;
+    what the kernel removes is the per-step launch and HBM round trip of
+    an XLA loop. The remaining capacities, in the Eq. (57) server order,
+    stay in ``[ceil(S/128), 128]`` registers for the whole slot while a
+    ``fori_loop`` reads each sorted camera's demands as SMEM scalars; the
+    f32 compares, divisions and ``max(rem - demand, 0)`` update are those
+    of ``binpack.first_fit_jax``, and the lines 6-8 fallback breaks
+    ``rem_vol`` ties to the lowest server id as its ``argmax`` does, so
+    the placements are identical.
 """
 from __future__ import annotations
 
@@ -669,3 +681,96 @@ def baseline_argmax(b, c, acc, xi, size, eff, *, mode: str, threshold,
         interpret=interpret,
         name="slot_solver.baseline_argmax",
     )(th, xi, size.reshape(1, n_r), b, c, eff, acc)
+
+
+# ---------------------------------------------------------------------------
+# First-fit server selection (Algorithm 2 lines 3-9)
+# ---------------------------------------------------------------------------
+
+def _fold(op, x):
+    """``[R, 128]`` -> ``[1, 1]`` reduction by ``op``: lanes, then
+    sublanes."""
+    x = op(x, axis=1, keepdims=True)
+    return x if x.shape[0] == 1 else op(x, axis=0, keepdims=True)
+
+
+def _first_fit_kernel(tot_ref, bc_ref, rem_b0_ref, rem_c0_ref, sid_ref,
+                      pos_ref, rem_b_ref, rem_c_ref, *, n: int,
+                      block_n: int):
+    """Place one block of sorted cameras; the capacities left carry over
+    to the next block in VMEM scratch."""
+    blk = pl.program_id(0)
+
+    @pl.when(blk == 0)
+    def _():
+        rem_b_ref[...] = rem_b0_ref[...]
+        rem_c_ref[...] = rem_c0_ref[...]
+
+    tot_b = tot_ref[0, 0]
+    tot_c = tot_ref[0, 1]
+    sid = sid_ref[...]                  # original server id of each lane
+    shape = sid.shape
+    flat = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * LANES
+            + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            ).astype(jnp.float32)       # position in the Eq. (57) order
+    none = jnp.float32(shape[0] * LANES)   # past every lane and server id
+    out = pos_ref.shape
+    cam = (jax.lax.broadcasted_iota(jnp.int32, out, 0) * LANES
+           + jax.lax.broadcasted_iota(jnp.int32, out, 1))
+
+    def place(j, state):
+        rem_b, rem_c, pos = state
+        bn = bc_ref[0, j]
+        cn = bc_ref[1, j]
+        fits = (rem_b >= bn) & (rem_c >= cn)      # padding lanes hold -inf
+        first = _fold(jnp.min, jnp.where(fits, flat, none))
+        # Lines 6-8: the most remaining volume, ties to the lowest id.
+        rem_vol = rem_b / tot_b + rem_c / tot_c
+        top = _fold(jnp.max, rem_vol)
+        best = _fold(jnp.min, jnp.where(rem_vol == top, sid, none))
+        fit = first < none
+        take = jnp.where(fit, flat, sid) == jnp.where(fit, first, best)
+        rem_b = jnp.where(take, jnp.maximum(rem_b - bn, 0.0), rem_b)
+        rem_c = jnp.where(take, jnp.maximum(rem_c - cn, 0.0), rem_c)
+        pos = jnp.where(cam == j, _fold(jnp.min, jnp.where(take, flat, none)),
+                        pos)
+        return rem_b, rem_c, pos
+
+    count = n if n <= block_n else jnp.minimum(block_n, n - blk * block_n)
+    rem_b, rem_c, pos = jax.lax.fori_loop(
+        0, count, place, (rem_b_ref[...], rem_c_ref[...],
+                          jnp.zeros(out, jnp.float32)))
+    rem_b_ref[...] = rem_b
+    rem_c_ref[...] = rem_c
+    pos_ref[...] = pos
+
+
+@functools.partial(jax.jit, static_argnames=("n", "block_n", "interpret"))
+def first_fit(tot, bc, rem_b, rem_c, sid, *, n: int, block_n: int,
+              interpret: bool = False):
+    """Algorithm 2's first-fit over cameras already in Eq. (56) order.
+
+    ``tot`` is ``[1, 2]`` (the fleet's total bandwidth and compute),
+    ``bc`` the ``[2, Np]`` sorted demands (``Np`` a multiple of
+    ``block_n``, itself the whole of ``Np`` or a multiple of 1024),
+    ``rem_b``/``rem_c`` the ``[R, 128]`` capacities in Eq. (57) order
+    with ``-inf`` on padding lanes and ``sid`` each lane's original server
+    id as f32. The grid walks ``block_n`` cameras a step. Returns the
+    ``[Np // 128, 128]`` f32 lane position chosen for each camera.
+    """
+    n_pad = bc.shape[1]
+    state = pl.BlockSpec(rem_b.shape, lambda j: (0, 0))
+    kernel = functools.partial(_first_fit_kernel, n=n, block_n=block_n)
+    return pl.pallas_call(
+        kernel,
+        grid=(n_pad // block_n,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec((2, block_n), lambda j: (0, j),
+                               memory_space=pltpu.SMEM),
+                  state, state, state],
+        out_specs=pl.BlockSpec((block_n // LANES, LANES), lambda j: (j, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_pad // LANES, LANES), jnp.float32),
+        scratch_shapes=[pltpu.VMEM(rem_b.shape, jnp.float32)] * 2,
+        interpret=interpret,
+        name="slot_solver.first_fit",
+    )(tot, bc, rem_b, rem_c, sid)

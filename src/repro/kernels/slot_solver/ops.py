@@ -380,3 +380,47 @@ def waterfill_pair(k, p, pol, mu, inv_xi, server_id, budgets_b, budgets_c,
         final_inner_iters=final_inner_iters,
         interpret=_resolve_interpret(interpret))
     return (layout.scatter_flat(u, n) * B, layout.scatter_flat(v, n) * C)
+
+
+# ---------------------------------------------------------------------------
+# First-fit server selection (Algorithm 2 lines 3-9)
+# ---------------------------------------------------------------------------
+
+#: Sorted cameras per grid step of the first-fit kernel: their demands sit
+#: in SMEM, 32 KiB a block, double-buffered well inside v5e's 1 MiB.
+FIRST_FIT_BLOCK_N = 4096
+
+
+def first_fit(b_sorted, c_sorted, rem_b, rem_c, srv_order, tot_b, tot_c,
+              interpret: bool | None = None):
+    """Kernel twin of ``binpack.first_fit_jax``'s placement loop.
+
+    ``b_sorted``/``c_sorted`` are the demands in Eq. (56) order,
+    ``rem_b``/``rem_c`` the budgets in Eq. (57) order, ``srv_order`` the
+    server id at each of those positions and ``tot_b``/``tot_c`` the
+    fleet's totals (demands and capacities float32). Returns int32[N]:
+    the position, in ``srv_order``, of the server each sorted camera is
+    placed on. The servers lie on ``ceil(S/128)`` rows of 128 lanes;
+    padding lanes hold ``-inf`` capacity, so they never fit and never
+    hold the most volume.
+    """
+    obs.count_dispatch("first_fit")
+    n = b_sorted.shape[0]
+    s = rem_b.shape[0]
+    n_pad = max(_LANE, -(-n // _LANE) * _LANE)
+    block = min(n_pad, FIRST_FIT_BLOCK_N)
+    n_pad = -(-n_pad // block) * block
+    s_pad = -(-s // _LANE) * _LANE
+
+    def lanes(x, fill):
+        return jnp.pad(jnp.asarray(x, jnp.float32), (0, s_pad - s),
+                       constant_values=fill).reshape(-1, _LANE)
+
+    bc = jnp.pad(jnp.stack([b_sorted, c_sorted]).astype(jnp.float32),
+                 ((0, 0), (0, n_pad - n)))
+    tot = jnp.stack([tot_b, tot_c]).astype(jnp.float32).reshape(1, 2)
+    pos = kernel.first_fit(tot, bc, lanes(rem_b, -jnp.inf),
+                           lanes(rem_c, -jnp.inf), lanes(srv_order, s_pad),
+                           n=n, block_n=block,
+                           interpret=_resolve_interpret(interpret))
+    return pos.reshape(-1)[:n].astype(jnp.int32)

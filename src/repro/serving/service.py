@@ -443,8 +443,10 @@ class AnalyticsService:
             return ctrl._rollout(tables)
 
     def _solver_attrs(self, tables: HorizonTables) -> dict:
-        """The ``planner.dispatch`` span's fleet size and the slot solver
-        it resolves to there (``bcd.resolve_spec``)."""
+        """The ``planner.dispatch`` span's fleet size, the slot solver it
+        resolves to there (``bcd.resolve_spec``) and the first-fit it runs:
+        ``lbcd.rollout`` takes the kernel where that solver is pallas; the
+        baselines keep the ``fori_loop``."""
         if not obs.enabled():
             return {}
         ctrl = self.controller
@@ -455,8 +457,10 @@ class AnalyticsService:
         spec = bcd.resolve_spec(backend, n,
                                 method=getattr(ctrl, "method", "waterfill"),
                                 masked=tables.active is not None)
-        return {"backend": str(spec), "n_cameras": n,
-                "n_servers": int(tables.budgets_b.shape[1])}
+        first_fit = (spec.backend if isinstance(ctrl, LBCDController)
+                     else "jnp")
+        return {"backend": str(spec), "first_fit": first_fit,
+                "n_cameras": n, "n_servers": int(tables.budgets_b.shape[1])}
 
     def _slot_record(self, t: int) -> lbcd.SlotRecord:
         if self.planner != "scan":

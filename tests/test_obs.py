@@ -451,8 +451,29 @@ def test_dispatch_spans_name_the_fleet_and_its_resolved_solver(served):
     dispatch = [e for e in events if e["name"] == "planner.dispatch"]
     assert len(dispatch) == 2
     for e in dispatch:
-        assert e["args"] == {"k": 2, "backend": "jnp", "n_cameras": 4,
-                             "n_servers": 2}
+        assert e["args"] == {"k": 2, "backend": "jnp", "first_fit": "jnp",
+                             "n_cameras": 4, "n_servers": 2}
+
+
+@pytest.mark.parametrize("n_cameras,first_fit",
+                         [(30, "jnp"), (128, "pallas")])
+def test_dispatch_spans_name_the_first_fit_the_plan_runs(n_cameras,
+                                                         first_fit):
+    """Under ``auto`` the rollout places cameras with the first-fit
+    kernel from ``bcd.AUTO_PALLAS_MIN_CAMERAS`` cameras, with the XLA
+    loop below; the span says which."""
+    from repro.core import lbcd, profiles
+    from repro.serving import AnalyticsService
+    system = profiles.EdgeSystem(n_cameras=n_cameras, n_servers=3,
+                                 n_slots=2, seed=0)
+    svc = AnalyticsService(
+        lbcd.LBCDController(system, v=10.0, p_min=0.6,
+                            solver_backend="auto"),
+        mode="mm1", plan_window=1)
+    svc.plan_horizon(1)
+    (e,) = [e for e in obs.events() if e["name"] == "planner.dispatch"]
+    assert e["args"]["first_fit"] == first_fit
+    assert e["args"]["backend"] == first_fit
 
 
 def test_inputs_spans_count_the_data_plane_dispatches(served):

@@ -462,6 +462,22 @@ def test_auto_backend_dispatch_choice_pinned():
     assert _prim_counts(jx.jaxpr).get("pallas_call", 0) == 3
 
 
+@pytest.mark.parametrize("n,backend,engaged", [
+    (30, "auto", False), (bcd.AUTO_PALLAS_MIN_CAMERAS, "auto", True),
+    (30, "pallas", True), (bcd.AUTO_PALLAS_MIN_CAMERAS, "jnp", False)])
+def test_rollout_runs_first_fit_as_a_kernel_on_the_pallas_path(n, backend,
+                                                               engaged):
+    """The rollout places cameras with the ``first_fit`` kernel exactly
+    where its slot solver resolves to pallas, once a slot."""
+    tab = profiles.EdgeSystem(n_cameras=n, n_servers=3, n_slots=2,
+                              seed=0).horizon(2)
+    jx = jax.make_jaxpr(functools.partial(
+        lbcd.rollout, solver_backend=backend))(tab, 10.0, 0.7)
+    names = [e.params["name"] for e in _walk_eqns(jx.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert names.count("slot_solver.first_fit") == int(engaged)
+
+
 def test_auto_backend_grid_path_switch():
     """The jnp fallback below the switch point also holds on the vmapped
     (V, P_min) grid path: an auto grid over a small fleet traces zero

@@ -134,3 +134,24 @@ def test_vmapped_solve_slot_compiles(one_chip):
         ((k, n), I32), ((k, s), F32), ((k, s), F32), ((k,), F32),
         ((k,), F32))
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("n,s", [(816, 125), (10_000, 100), (300, 200)])
+def test_first_fit_compiles(one_chip, n, s):
+    """Algorithm 2's first-fit kernel: one row of servers (125), more
+    than one (200), and cameras over several grid steps (10^4)."""
+    from repro.core import binpack
+    hlo = _compile(one_chip, lambda b, c, bb, bc: binpack.first_fit_jax(
+        b, c, bb, bc, backend="pallas", interpret=False),
+        ((n,), F32), ((n,), F32), ((s,), F32), ((s,), F32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_vmapped_first_fit_compiles(one_chip):
+    from repro.core import binpack
+    k, n, s = 3, 816, 125
+    hlo = _compile(one_chip, jax.vmap(
+        lambda b, c, bb, bc: binpack.first_fit_jax(
+            b, c, bb, bc, backend="pallas", interpret=False)),
+        ((k, n), F32), ((k, n), F32), ((k, s), F32), ((k, s), F32))
+    assert "tpu_custom_call" in hlo
