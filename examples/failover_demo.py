@@ -1,6 +1,6 @@
-"""Failover demo: an island (model-parallel subgroup) dies mid-service and
-LBCD's server-selection subproblem re-places its streams on the next epoch
-(the paper's Algorithm 2 doubling as the fault-tolerance mechanism).
+"""Failover demo: an edge server ("island") dies mid-service and LBCD's
+server-selection subproblem re-places its streams on the next epoch (the
+paper's Algorithm 2 doubling as the fault-tolerance mechanism).
 
     PYTHONPATH=src python examples/failover_demo.py
 """
@@ -9,7 +9,7 @@ from pathlib import Path
 import numpy as np
 
 from repro.core import lbcd, profiles
-from repro.training.failure import failover_assignment
+from repro.faults import failover_assignment
 
 
 def main():

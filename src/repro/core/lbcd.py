@@ -236,8 +236,8 @@ def summarize(res: RolloutResult, v: float, p_min: float) -> RunSummary:
 
 class LBCDController:
     """The paper's controller; also reused as the serving-runtime planner
-    (repro.serving.service) and the island-failover mechanism
-    (repro.training.failure)."""
+    (repro.serving.service) and the server-failover mechanism
+    (repro.faults.failover_assignment)."""
 
     def __init__(self, system: EdgeSystem, v: float = 10.0,
                  p_min: float = 0.7, n_bcd_iters: int = 4,

@@ -19,11 +19,8 @@ Provides the substrate the controller consumes each slot:
   * bandwidth / compute capacity traces shaped like the Ghent LTE and
     Bitbrains datacenter traces used in §VI-A (lognormal AR(1) modulation).
 
-Two candidate pools ship out of the box:
-  * ``paper_pool()``  — the paper's own ladder (YOLOv5n..x, FPN, U-Net,
-    YOLACT, Mask R-CNN) with public FLOPs/params numbers;
-  * ``lm_pool()``     — the assigned LM-architecture ladder, where a
-    "frame" is a patch/token bundle and resolution maps to patch count.
+The candidate pool is ``paper_pool()``: the paper's own ladder (YOLOv5n..x,
+FPN, U-Net, YOLACT, Mask R-CNN) with public FLOPs/params numbers.
 """
 from __future__ import annotations
 
@@ -77,28 +74,6 @@ def paper_pool() -> list[ModelCandidate]:
         ModelCandidate("unet", 31.0, 120.0, 0.84, 220.0, task="segmentation"),
         ModelCandidate("yolact", 34.7, 61.6, 0.78, 210.0, task="instance"),
         ModelCandidate("mask_rcnn", 44.2, 134.0, 0.86, 225.0, task="instance"),
-    ]
-
-
-def lm_pool() -> list[ModelCandidate]:
-    """Assigned-architecture ladder for pod-scale serving. xi is calibrated
-    as 2 * N_active * tokens(r), tokens(r) = (r/16)^2 vision patches; the
-    gflops_ref column folds that in at r=640 (1600 patches)."""
-    def g(n_active_b):  # GFLOPs per frame at 640p (1600 tokens)
-        return 2.0 * n_active_b * 1e9 * (640 / 16) ** 2 / 1e9
-
-    return [
-        ModelCandidate("qwen2.5-3b", 3_000, g(3.0), 0.74, 205.0, task="lm"),
-        ModelCandidate("yi-6b", 6_000, g(6.0), 0.78, 210.0, task="lm"),
-        ModelCandidate("minicpm3-4b", 4_000, g(4.0), 0.76, 208.0, task="lm"),
-        ModelCandidate("qwen2-moe-a2.7b", 14_000, g(2.7), 0.75, 206.0,
-                       task="lm"),
-        ModelCandidate("llama-3.2-vision-11b", 11_000, g(11.0), 0.82, 215.0,
-                       task="vlm"),
-        ModelCandidate("yi-34b", 34_000, g(34.0), 0.87, 222.0, task="lm"),
-        ModelCandidate("dbrx-132b", 132_000, g(36.0), 0.89, 226.0, task="lm"),
-        ModelCandidate("jamba-1.5-large-398b", 398_000, g(98.0), 0.91, 230.0,
-                       task="lm"),
     ]
 
 

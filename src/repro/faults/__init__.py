@@ -24,6 +24,10 @@ Fault kinds split into three delivery mechanisms:
 ``faults=None`` everywhere is the bitwise no-op path: no ``active`` leaf is
 attached, no budget is touched, and every downstream trace is byte-identical
 to a pre-fault-plane build (pinned by ``tests/test_faults.py``).
+
+:func:`failover_assignment` is the outage response: one controller epoch
+with the dead servers' ("islands'") budgets zeroed, so Algorithm 2's
+first-fit re-places their streams on the survivors.
 """
 from __future__ import annotations
 
@@ -280,3 +284,41 @@ def storm_plan(n_slots: int, *, seed: int = 0,
                       params={"attempts": 8}),
         ]
     return FaultPlan(tuple(specs), seed=seed)
+
+
+# ---------------------------------------------------------------------------
+# Server failover: Algorithm 2 re-run with dead servers masked out
+# ---------------------------------------------------------------------------
+
+def fail_islands(budgets_b: np.ndarray, budgets_c: np.ndarray,
+                 dead: np.ndarray):
+    """Zero the capacities of dead islands (input to the LBCD re-solve)."""
+    b = np.asarray(budgets_b, np.float64).copy()
+    c = np.asarray(budgets_c, np.float64).copy()
+    b[dead] = 0.0
+    c[dead] = 0.0
+    return b, c
+
+
+def failover_assignment(controller, t: int, dead: np.ndarray):
+    """One controller epoch with dead islands masked out.
+
+    ``controller``: repro.core.lbcd.LBCDController. Streams on dead islands
+    are re-placed by the same first-fit machinery (Algorithm 2); returns the
+    new SlotRecord.
+    """
+    sys = controller.system
+    orig = sys.capacities
+
+    def masked(tt):
+        b, c = orig(tt)
+        return fail_islands(b, c, dead)
+
+    sys.capacities = masked
+    try:
+        rec = controller.step(t)
+    finally:
+        sys.capacities = orig
+    assert not np.asarray(dead)[rec.assign].any(), \
+        "failover left a stream on a dead island"
+    return rec

@@ -43,7 +43,6 @@ class ScenarioSpec:
     mean_bandwidth_hz: float = 30e6
     mean_compute_flops: float = 50e12
     seed: int = 0
-    pool: str = "paper"                  # "paper" | "lm"
     resolutions: Sequence[int] = profiles.RESOLUTIONS
     alpha: float = profiles.ALPHA_BITS_PER_PIXEL
     params: Mapping = dataclasses.field(default_factory=dict)
@@ -119,14 +118,6 @@ def default_components(spec: ScenarioSpec) -> Components:
         drift=base_drift(spec))
 
 
-def pool_for(spec: ScenarioSpec) -> list[profiles.ModelCandidate]:
-    if spec.pool == "paper":
-        return profiles.paper_pool()
-    if spec.pool == "lm":
-        return profiles.lm_pool()
-    raise ValueError(f"unknown pool {spec.pool!r} (expected 'paper'|'lm')")
-
-
 def assemble(spec: ScenarioSpec, comps: Components,
              dtype=jnp.float32) -> HorizonTables:
     """Fold components + model-pool profiles into one ``HorizonTables``.
@@ -148,7 +139,7 @@ def assemble(spec: ScenarioSpec, comps: Components,
     if comps.active is not None and comps.active.shape != (t_len, n):
         raise ValueError(f"active shape {comps.active.shape} != "
                          f"(T={t_len}, N={n})")
-    pool = pool_for(spec)
+    pool = profiles.paper_pool()
     res = np.asarray(spec.resolutions, np.float64)
     difficulty = rng(spec, "difficulty").uniform(0.88, 1.0, n)
     zr = np.stack([m.zeta(res) for m in pool])              # [M, R]

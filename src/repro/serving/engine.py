@@ -1,24 +1,27 @@
 """Continuous-batching inference engine with step-boundary preemption.
 
-Lanes hold per-sequence KV/state cache slots inside one batched cache tree;
-``decode_tick`` advances every active lane with a single jitted decode step
-(ragged lengths via the cache's per-lane ``len``). LCFSP preemption frees a
-lane between steps — the scheduler (repro.serving.scheduler) decides when.
+The engine rung of the truth ladder replays frames through it. Lanes hold
+per-request recurrent-state slots inside one batched cache (a per-lane
+``len`` and a ``state`` row); ``decode_tick`` advances every active lane
+with a single jitted decode step. LCFSP preemption frees a lane between
+steps; the scheduler (repro.serving.scheduler) decides when.
 
 A "frame analysis" request = prefill(frame tokens) + ``decode_tokens``
 decode steps (the recognition head of the paper's detection task mapped to
-autoregressive analysis output).
+autoregressive analysis output). The model is
+:class:`NullAnalyticsModel`, a stub whose cost is negligible: the rung's
+timing comes from sampled service draws, not model FLOPs.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, List, Optional
+import math
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.common import init_params
 from .scheduler import Frame
 
 FREE, DECODING = 0, 2
@@ -40,39 +43,20 @@ class Result:
     t_done: float = 0.0
 
 
-def _insert_lane(batched, single, lane: int):
-    """Copy a 1-lane cache into lane ``lane`` of the batched cache.
-
-    Block-stack leaves carry a leading n_periods dim ([P, lanes, ...]); the
-    top-level ``len`` leaf is [lanes]. Dispatch on rank difference."""
-    def ins(b, s):
-        if b.ndim == s.ndim and b.shape[0] == s.shape[0] and b.ndim >= 2:
-            return b.at[:, lane].set(s[:, 0])      # [P, lanes, ...]
-        return b.at[lane].set(s[0])                # [lanes, ...]
-    return jax.tree.map(ins, batched, single)
-
-
 class Engine:
     def __init__(self, model, params, n_lanes: int = 8, max_len: int = 256,
-                 decode_tokens: int = 8, key=None):
+                 decode_tokens: int = 8):
         self.model = model
         self.params = params
         self.n_lanes = n_lanes
         self.max_len = max_len
         self.decode_tokens = decode_tokens
-        key = key if key is not None else jax.random.PRNGKey(0)
-        self.cache = init_params(
-            model.cache_template(n_lanes, max_len), key)
+        self.cache = model.init_cache(n_lanes, max_len)
         self.lanes: List[LaneState] = [LaneState() for _ in range(n_lanes)]
-        self._decode = jax.jit(
-            lambda p, t, c: model.decode_step(p, t, c))
-        self._prefill = jax.jit(
-            lambda p, b, c: model.prefill(p, b, c))
         # Fused admit: prefill + lane insert + first-token argmax in ONE
         # dispatch (dynamic lane index), over a memoized single-lane
-        # cache — per-admit init_params dominated replay-plane runtime.
-        self._single_cache = init_params(
-            model.cache_template(1, max_len), jax.random.PRNGKey(0))
+        # cache — a fresh cache per admit dominated replay-plane runtime.
+        self._single_cache = model.init_cache(1, max_len)
 
         def _admit_fused(p, tokens, batched, single, lane):
             logits, single = model.prefill(p, {"tokens": tokens}, single)
@@ -183,9 +167,9 @@ class NullAnalyticsModel:
     The truth-ladder engine rung needs the *batching/lane mechanics* of a
     real continuous-batching engine — admit/prefill/decode_tick/preempt —
     at suite scale, where timing comes from sampled service draws, not
-    model FLOPs. This stub satisfies the model protocol (``template`` /
-    ``cache_template`` / ``prefill`` / ``decode_step``) with a cumsum-
-    embed recurrent cell small enough that thousands of frames cost
+    model FLOPs. This stub satisfies the model protocol (``init`` /
+    ``init_cache`` / ``prefill`` / ``decode_step``) with a cumsum-embed
+    recurrent cell small enough that thousands of frames cost
     milliseconds, while staying fully deterministic under a fixed key.
     """
 
@@ -193,20 +177,22 @@ class NullAnalyticsModel:
         self.d = d
         self.vocab = vocab
 
-    def template(self):
-        from ..models import common as c
-        return {"emb": c.P((self.vocab, self.d), (c.VOCAB, c.EMBED),
-                           init="embed"),
-                "out": c.P((self.d, self.vocab), (c.EMBED, c.VOCAB))}
+    def init(self, key):
+        """Parameters: ``emb`` [vocab, d] ~ N(0, 1) and ``out`` [d, vocab]
+        ~ N(0, 1/d), drawn from the two halves of ``key``."""
+        k_emb, k_out = jax.random.split(key, 2)
+        return {"emb": jax.random.normal(k_emb, (self.vocab, self.d),
+                                         jnp.float32),
+                "out": jax.random.normal(k_out, (self.d, self.vocab),
+                                         jnp.float32)
+                * (1.0 / math.sqrt(self.d))}
 
-    def cache_template(self, lanes: int, max_len: int):
-        from ..models import common as c
-        # Leading extent-1 dim on "state" takes _insert_lane's stacked
-        # ([P, lanes, ...]) path; "len" takes the flat [lanes] path.
-        return {"len": c.P((lanes,), (None,), init="zeros",
-                           dtype=jnp.int32),
-                "state": c.P((1, lanes, self.d), (None, None, c.EMBED),
-                             init="zeros")}
+    def init_cache(self, lanes: int, max_len: int):
+        """Empty cache for ``lanes`` lanes. The leading extent-1 dim on
+        ``state`` takes the admit's stacked ([1, lanes, d]) insert path;
+        ``len`` takes the flat [lanes] one."""
+        return {"len": jnp.zeros((lanes,), jnp.int32),
+                "state": jnp.zeros((1, lanes, self.d), jnp.float32)}
 
     def prefill(self, params, batch, cache):
         tok = batch["tokens"]                       # [B, S]
@@ -230,6 +216,6 @@ def make_replay_engine(n_lanes: int, *, max_len: int = 64,
     """Engine over :class:`NullAnalyticsModel` for the replay plane —
     deterministic under ``seed``, one lane per replayed stream."""
     model = NullAnalyticsModel()
-    params = init_params(model.template(), jax.random.PRNGKey(seed))
-    return Engine(model, params, n_lanes=n_lanes, max_len=max_len,
-                  decode_tokens=decode_tokens, key=jax.random.PRNGKey(seed))
+    return Engine(model, model.init(jax.random.PRNGKey(seed)),
+                  n_lanes=n_lanes, max_len=max_len,
+                  decode_tokens=decode_tokens)

@@ -4,12 +4,11 @@ This is the paper's computation-policy layer mapped onto a serving
 scheduler: each stream (camera) owns a frame queue; under FCFS frames are
 processed in arrival order, under LCFSP a newly-arrived frame *preempts*
 the stream's in-flight frame at the next step boundary (TPUs cannot abort
-an MXU op mid-flight — preemption granularity is one engine step, the
-assumption change recorded in DESIGN.md §2).
+an MXU op mid-flight, so preemption granularity is one engine step).
 
 ``AoPITracker`` integrates the exact piecewise-linear age curve online —
-the measured counterpart of Theorems 1-2, compared against the closed forms
-in tests/test_serving.py and examples/serve_e2e.py.
+the measured counterpart of Theorems 1-2, compared against the offline
+integrator in tests/test_serving.py.
 """
 from __future__ import annotations
 

@@ -36,8 +36,9 @@ Two data planes ship:
     model-vs-measurement split where config-adaptation policies break.
     ``replan_threshold`` arms divergence-triggered replanning: a mid-
     window drift past the threshold cuts the window and replans early.
-  * ``mode="engine"`` — a real continuous-batching Engine on a small model
-    (examples/serve_e2e.py), with LCFSP preemption at step boundaries.
+  * ``mode="engine"`` — the engine rung: frames replayed through the
+    continuous-batching Engine over its stub model
+    (``make_replay_engine``), with LCFSP preemption at step boundaries.
 """
 from __future__ import annotations
 

@@ -1,6 +1,6 @@
 """Test-wide config. NOTE: no XLA_FLAGS here — smoke tests and benches see
-the real single CPU device; only launch/dryrun.py requests 512 fake
-devices (per its first two lines)."""
+the real single CPU device; a test that needs several devices starts a
+subprocess with its own flags."""
 import warnings
 
 warnings.filterwarnings("ignore", category=DeprecationWarning)

@@ -6,7 +6,16 @@ import pytest
 from repro.core import allocate, aopi
 
 
-def _setup(n, s, seed=0, lcfsp_frac=0.5):
+# Cameras per server: paper-30 runs 10, eua-816 about 7 (816 on 125).
+# ORIGINAL stands for each test's first instance: random server ids and
+# the budgets it has always had.
+LOADS = (1, 3, 7, 10, 64)
+ORIGINAL = pytest.param(None, id="original")
+
+
+def _setup(n, s, seed=0, lcfsp_frac=0.5, even=False):
+    """Random bandwidth-allocation instance; ``even`` puts exactly n / s
+    cameras on each server."""
     rng = np.random.default_rng(seed)
     k = rng.uniform(1e-6, 5e-6, n)          # lam per Hz
     p = rng.uniform(0.3, 0.95, n)
@@ -14,6 +23,8 @@ def _setup(n, s, seed=0, lcfsp_frac=0.5):
     mu = rng.uniform(5.0, 40.0, n)
     server_id = rng.integers(0, s, n).astype(np.int32)
     budgets = rng.uniform(2e7, 5e7, s)
+    if even:
+        server_id = rng.permutation(np.arange(n) % s).astype(np.int32)
     return (jnp.asarray(k, jnp.float32), jnp.asarray(p, jnp.float32),
             jnp.asarray(pol), jnp.asarray(mu, jnp.float32),
             jnp.asarray(server_id), jnp.asarray(budgets, jnp.float32))
@@ -28,8 +39,10 @@ def _obj_bandwidth(b, k, p, pol, mu):
     return a.sum()
 
 
-def test_bandwidth_budget_respected():
-    k, p, pol, mu, sid, B = _setup(12, 3)
+@pytest.mark.parametrize("load", (ORIGINAL,) + LOADS)
+def test_bandwidth_budget_respected(load):
+    k, p, pol, mu, sid, B = (_setup(12, 3) if load is None
+                             else _setup(3 * load, 3, even=True))
     b = allocate.waterfill_bandwidth(k, p, pol, mu, sid, B, n_servers=3)
     b = np.asarray(b)
     assert (b > 0).all()
@@ -37,18 +50,39 @@ def test_bandwidth_budget_respected():
         assert b[np.asarray(sid) == s].sum() <= float(B[s]) * 1.001
 
 
-def test_compute_budget_respected_and_stability():
-    rng = np.random.default_rng(1)
-    n, s = 10, 2
-    inv_xi = jnp.asarray(rng.uniform(1e-12, 5e-12, n), jnp.float32)
-    p = jnp.asarray(rng.uniform(0.3, 0.95, n), jnp.float32)
-    pol = jnp.asarray((rng.random(n) < 0.5).astype(np.int32))
-    lam = jnp.asarray(rng.uniform(1.0, 10.0, n), jnp.float32)
-    sid = jnp.asarray(rng.integers(0, s, n).astype(np.int32))
+def _compute_setup(seed, load, lam_hi, n_original):
+    """Random compute-allocation instance on two servers. ``load=None`` is
+    the original instance: ``n_original`` cameras on random servers with
+    budgets of 3e13-8e13 FLOPS, several times the FCFS stability floors.
+    Otherwise ``load`` cameras sit on each server, and a server's budget is
+    1.05-1.5 times the compute that would serve each of its cameras at
+    mu = lam, so the floors leave little slack."""
+    rng = np.random.default_rng(seed)
+    s = 2
+    n = n_original if load is None else s * load
+    inv_xi = rng.uniform(1e-12, 5e-12, n)
+    p = rng.uniform(0.3, 0.95, n)
+    pol = (rng.random(n) < 0.5).astype(np.int32)
+    lam = rng.uniform(1.0, lam_hi, n)
+    if load is None:
+        sid = rng.integers(0, s, n).astype(np.int32)
+        C = rng.uniform(3e13, 8e13, s)
+    else:
+        sid = rng.permutation(np.arange(n) % s).astype(np.int32)
+        floor = np.bincount(sid, weights=lam / inv_xi, minlength=s)
+        C = floor * rng.uniform(1.05, 1.5, s)
+    return (jnp.asarray(inv_xi, jnp.float32), jnp.asarray(p, jnp.float32),
+            jnp.asarray(pol), jnp.asarray(lam, jnp.float32),
+            jnp.asarray(sid), jnp.asarray(C, jnp.float32), s)
+
+
+@pytest.mark.parametrize("load", (ORIGINAL,) + LOADS)
+def test_compute_budget_respected_and_stability(load):
     # Budgets large enough that the FCFS stability floors are feasible
     # (the config-selection step guarantees this in the full controller;
     # infeasible instances get documented best-effort scaling instead).
-    C = jnp.asarray(rng.uniform(3e13, 8e13, s), jnp.float32)
+    inv_xi, p, pol, lam, sid, C, s = _compute_setup(1, load, lam_hi=10.0,
+                                                n_original=10)
     c = np.asarray(allocate.waterfill_compute(inv_xi, p, pol, lam, sid, C,
                                               n_servers=s))
     assert (c > 0).all()
@@ -59,18 +93,22 @@ def test_compute_budget_respected_and_stability():
     assert (mu[fcfs] > np.asarray(lam)[fcfs]).all()   # constraint (10)
 
 
-def test_waterfill_kkt_equal_marginals():
+@pytest.mark.parametrize("load", (1, 3, 7, 9, 10, 64))
+def test_waterfill_kkt_equal_marginals(load):
     """At the optimum, active (uncapped) cameras on one server share the
-    same marginal -dA/db (the dual nu_s)."""
-    k, p, pol, mu, sid, B = _setup(9, 1, seed=3, lcfsp_frac=1.0)
+    same marginal -dA/db (the dual nu_s). Load 9 is the original
+    instance."""
+    k, p, pol, mu, sid, B = _setup(load, 1, seed=3, lcfsp_frac=1.0)
     b = allocate.waterfill_bandwidth(k, p, pol, mu, sid, B, n_servers=1)
     lam = np.asarray(b) * np.asarray(k)
     h = (1.0 + 1.0 / np.asarray(p)) / lam**2 * np.asarray(k)  # -dA/db
     assert h.std() / h.mean() < 0.02
 
 
-def test_interior_point_matches_waterfill_bandwidth():
-    k, p, pol, mu, sid, B = _setup(8, 2, seed=5)
+@pytest.mark.parametrize("load", (ORIGINAL,) + LOADS)
+def test_interior_point_matches_waterfill_bandwidth(load):
+    k, p, pol, mu, sid, B = (_setup(8, 2, seed=5) if load is None
+                             else _setup(2 * load, 2, seed=5, even=True))
     b_wf = np.asarray(allocate.waterfill_bandwidth(
         k, p, pol, mu, sid, B, n_servers=2))
     b_ip = np.asarray(allocate.interior_point_bandwidth(
@@ -81,15 +119,10 @@ def test_interior_point_matches_waterfill_bandwidth():
     assert f_ip == pytest.approx(f_wf, rel=5e-3)
 
 
-def test_interior_point_matches_waterfill_compute():
-    rng = np.random.default_rng(7)
-    n, s = 8, 2
-    inv_xi = jnp.asarray(rng.uniform(1e-12, 5e-12, n), jnp.float32)
-    p = jnp.asarray(rng.uniform(0.3, 0.95, n), jnp.float32)
-    pol = jnp.asarray((rng.random(n) < 0.5).astype(np.int32))
-    lam = jnp.asarray(rng.uniform(1.0, 8.0, n), jnp.float32)
-    sid = jnp.asarray(rng.integers(0, s, n).astype(np.int32))
-    C = jnp.asarray(rng.uniform(3e13, 8e13, s), jnp.float32)
+@pytest.mark.parametrize("load", (ORIGINAL,) + LOADS)
+def test_interior_point_matches_waterfill_compute(load):
+    inv_xi, p, pol, lam, sid, C, s = _compute_setup(7, load, lam_hi=8.0,
+                                                n_original=8)
 
     def obj(c):
         mu = np.maximum(np.asarray(c) * np.asarray(inv_xi), 1e-9)

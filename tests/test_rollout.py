@@ -172,3 +172,27 @@ def test_horizon_tables_match_legacy_tables():
                                    rtol=1e-6)
         np.testing.assert_allclose(np.asarray(hor.budgets_c[t]), bc,
                                    rtol=1e-6)
+
+
+@pytest.mark.parametrize("short,long", [(8, 24), (24, 100)],
+                         ids=["inside", "past-n_slots"])
+@pytest.mark.parametrize("n_cameras,n_servers", [(30, 3), (12, 2)])
+@pytest.mark.parametrize("seed", range(3))
+def test_edge_system_horizon_is_prefix_stable(seed, n_cameras, n_servers,
+                                              short, long):
+    """horizon(short) is bitwise the first ``short`` slots of horizon(long),
+    also past the system's own n_slots, where the capacity traces wrap.
+    The service's source-horizon cache (``_base_window``) grows on this."""
+    system = _system(n_cameras=n_cameras, n_servers=n_servers, n_slots=24,
+                     seed=seed)
+    a, b = system.horizon(short), system.horizon(long)
+    assert a.n_slots == short and b.n_slots == long
+    assert a.active is None and b.active is None
+    for name in ("acc", "budgets_b", "budgets_c"):
+        np.testing.assert_array_equal(np.asarray(getattr(a, name)),
+                                      np.asarray(getattr(b, name))[:short],
+                                      err_msg=name)
+    for name in ("xi", "size", "eff"):
+        np.testing.assert_array_equal(np.asarray(getattr(a, name)),
+                                      np.asarray(getattr(b, name)),
+                                      err_msg=name)

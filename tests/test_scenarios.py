@@ -202,9 +202,9 @@ def test_sweep_runs_all_policies_and_reports():
     s = scenarios.suite(["steady_ar1", "gilbert_elliott", "server_outage"],
                         n_cameras=4, n_slots=6, n_servers=2,
                         mean_bandwidth_hz=15e6, mean_compute_flops=20e12)
-    # Pin one device: the suite may run with many virtual devices in the
-    # process (e.g. after launch/dryrun forces 512), and this test is about
-    # the vmap fallback semantics, not backend selection.
+    # Pin one device: the process may hold several virtual host devices
+    # (XLA_FLAGS can force them), and this test is about the vmap fallback
+    # semantics, not backend selection.
     res = scenarios.sweep(s, v=10.0, p_min=0.7, devices=jax.devices()[:1])
     assert res.backend == "vmap"
     assert set(res.policies) == set(scenarios.POLICIES)

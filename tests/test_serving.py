@@ -1,16 +1,14 @@
 """Serving: scheduler semantics, AoPI tracker, engine, LBCD-driven service."""
-import dataclasses
+import math
 
 import jax
 import numpy as np
 import pytest
 
-from repro import configs
-from repro.core import aopi, lbcd, profiles, queues
-from repro.models import build
-from repro.models.common import init_params
-from repro.serving import (AnalyticsService, AoPITracker, Engine, Frame,
-                           StreamQueue)
+from repro.core import lbcd, profiles, queues
+from repro.faults import failover_assignment
+from repro.serving import (AnalyticsService, AoPITracker, Frame, StreamQueue,
+                           make_replay_engine)
 from repro.serving.scheduler import FCFS, LCFSP
 
 
@@ -47,59 +45,82 @@ def test_aopi_tracker_matches_offline_integration():
     assert tr.mean_aopi(0, horizon) == pytest.approx(expect, rel=1e-9)
 
 
-def _tiny_engine(n_lanes=4, decode_tokens=2):
-    cfg = configs.get("qwen2.5-3b").reduced()
-    model = build(cfg)
-    params = init_params(model.template(), jax.random.PRNGKey(0))
-    return Engine(model, params, n_lanes=n_lanes, max_len=64,
-                  decode_tokens=decode_tokens), cfg
+@pytest.mark.parametrize("seed", range(5))
+def test_replay_engine_params_are_the_explicit_draws(seed):
+    """The replay engine's parameters are two normal draws from the halves
+    of PRNGKey(seed): emb [32, 8] at scale 1, out [8, 32] at 1/sqrt(8)."""
+    eng = make_replay_engine(3, seed=seed)
+    k_emb, k_out = jax.random.split(jax.random.PRNGKey(seed), 2)
+    emb = jax.random.normal(k_emb, (32, 8), np.float32)
+    out = jax.random.normal(k_out, (8, 32), np.float32) * (1.0 / math.sqrt(8))
+    assert sorted(eng.params) == ["emb", "out"]
+    np.testing.assert_array_equal(np.asarray(eng.params["emb"]), emb)
+    np.testing.assert_array_equal(np.asarray(eng.params["out"]), out)
+    assert eng.params["emb"].dtype == eng.params["out"].dtype == np.float32
+    np.testing.assert_array_equal(np.asarray(eng.cache["len"]),
+                                  np.zeros(3, np.int32))
+    np.testing.assert_array_equal(np.asarray(eng.cache["state"]),
+                                  np.zeros((1, 3, 8), np.float32))
 
 
-def test_engine_admit_decode_complete():
-    eng, cfg = _tiny_engine()
+# Lane counts x decode lengths the engine tests run over.
+ENGINE_SHAPES = pytest.mark.parametrize(
+    "n_lanes,decode_tokens", [(n, d) for n in (2, 4) for d in (2, 8)])
+
+
+@ENGINE_SHAPES
+def test_engine_admit_decode_complete(n_lanes, decode_tokens):
+    eng = make_replay_engine(n_lanes, decode_tokens=decode_tokens)
     f = Frame(3, 0.0, 0.0)
     assert eng.admit(f, np.arange(2, 10, dtype=np.int32))
-    assert eng.utilization == 0.25
+    assert eng.utilization == 1.0 / n_lanes
     done = []
-    for _ in range(5):
+    for _ in range(decode_tokens + 3):
         done += eng.decode_tick()
         if done:
             break
     assert done and done[0].stream_id == 3
-    assert len(done[0].tokens) == 3          # prefill token + 2 decode
+    assert len(done[0].tokens) == 1 + decode_tokens   # prefill token + decode
     assert eng.utilization == 0.0
+    assert len(eng.free_lanes()) == n_lanes
 
 
-def test_engine_preemption_frees_lane():
-    eng, cfg = _tiny_engine(n_lanes=2, decode_tokens=50)
-    eng.admit(Frame(1, 0.0, 0.0), np.arange(2, 8, dtype=np.int32))
-    eng.admit(Frame(2, 0.0, 0.0), np.arange(2, 8, dtype=np.int32))
+@ENGINE_SHAPES
+def test_engine_preemption_frees_lane(n_lanes, decode_tokens):
+    eng = make_replay_engine(n_lanes, decode_tokens=decode_tokens)
+    for i in range(n_lanes):
+        assert eng.admit(Frame(i + 1, 0.0, 0.0),
+                         np.arange(2, 8, dtype=np.int32))
     assert not eng.free_lanes()
+    assert not eng.admit(Frame(99, 0.0, 0.0), np.arange(2, 8, dtype=np.int32))
     assert eng.preempt_stream(1) == 1
     assert len(eng.free_lanes()) == 1
-    done = eng.decode_tick()                 # stream 2 still running
-    assert done == []
+    assert eng.lanes[0].stream_id == -1 and eng.lanes[0].remaining == 0
+    assert eng.decode_tick() == []          # the other streams still run
 
 
-def test_engine_batched_decode_matches_sequential():
-    """Two lanes decoding together produce the same tokens as alone."""
-    eng1, _ = _tiny_engine(n_lanes=1, decode_tokens=4)
-    toks_a = np.arange(2, 12, dtype=np.int32)
-    eng1.admit(Frame(0, 0, 0), toks_a)
-    out_solo = None
-    for _ in range(6):
-        r = eng1.decode_tick()
-        if r:
-            out_solo = r[0].tokens
-            break
-    eng2, _ = _tiny_engine(n_lanes=2, decode_tokens=4)
-    eng2.admit(Frame(0, 0, 0), toks_a)
-    eng2.admit(Frame(1, 0, 0), np.arange(30, 45, dtype=np.int32))
-    outs = {}
-    for _ in range(6):
-        for r in eng2.decode_tick():
-            outs[r.stream_id] = r.tokens
-    np.testing.assert_array_equal(outs[0], out_solo)
+@ENGINE_SHAPES
+def test_engine_batched_decode_matches_sequential(n_lanes, decode_tokens):
+    """Lanes decoding together produce the same tokens as each alone."""
+    prompts = [np.arange(2 + 3 * i, 12 + 2 * i, dtype=np.int32)
+               for i in range(n_lanes)]
+
+    def run(eng, idx):
+        for i in idx:
+            assert eng.admit(Frame(i, 0, 0), prompts[i])
+        outs = {}
+        for _ in range(decode_tokens + 2):
+            for r in eng.decode_tick():
+                outs[r.stream_id] = r.tokens
+        return outs
+
+    together = run(make_replay_engine(n_lanes, decode_tokens=decode_tokens),
+                   range(n_lanes))
+    assert sorted(together) == list(range(n_lanes))
+    for i in range(n_lanes):
+        solo = run(make_replay_engine(1, decode_tokens=decode_tokens), [i])
+        assert len(solo[i]) == 1 + decode_tokens
+        np.testing.assert_array_equal(together[i], solo[i])
 
 
 def test_service_measured_matches_closed_form():
@@ -118,25 +139,16 @@ def test_service_measured_matches_closed_form():
     assert np.median(ratio) == pytest.approx(1.0, abs=0.15)
 
 
-def test_failover_reassigns_streams():
-    from repro.training.failure import failover_assignment
+DEAD_SETS = [(0,), (1,), (2,), (0, 1), (0, 2), (1, 2)]
+
+
+@pytest.mark.parametrize("dead_servers", DEAD_SETS,
+                         ids=["-".join(map(str, d)) for d in DEAD_SETS])
+def test_failover_reassigns_streams(dead_servers):
     system = profiles.EdgeSystem(n_cameras=9, n_servers=3, n_slots=5,
                                  seed=5)
     ctrl = lbcd.LBCDController(system, v=10.0, p_min=0.6)
-    dead = np.array([False, True, False])
+    dead = np.zeros(3, bool)
+    dead[list(dead_servers)] = True
     rec = failover_assignment(ctrl, 0, dead)
     assert not dead[rec.assign].any()
-
-
-def test_straggler_monitor_flags_outlier():
-    from repro.training.failure import StragglerMonitor
-    mon = StragglerMonitor(n_workers=4, warmup=5)
-    rng = np.random.default_rng(0)
-    flagged = None
-    for t in range(30):
-        times = rng.normal(1.0, 0.02, 4)
-        times[2] += 0.0 if t < 10 else 2.0      # worker 2 degrades
-        flagged = mon.observe(times)
-    assert flagged[2] and not flagged[[0, 1, 3]].any()
-    w = mon.rebalance_weights()
-    assert w[2] == w.min()

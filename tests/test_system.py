@@ -44,21 +44,14 @@ def test_e2e_closed_form_guides_real_queues():
     assert np.mean(np.abs(pred - meas) / np.maximum(meas, 1e-9)) < 0.3
 
 
-def test_e2e_real_engine_service_runs():
-    """Tiny real-model engine driven by LBCD for one epoch."""
-    import jax
+@pytest.mark.parametrize("seed", range(3))
+def test_e2e_real_engine_service_runs(seed):
+    """The replay engine driven by LBCD for one engine-mode epoch."""
+    from repro.serving import make_replay_engine
 
-    from repro import configs
-    from repro.models import build
-    from repro.models.common import init_params
-    from repro.serving import Engine
-
-    cfg = configs.get("qwen2.5-3b").reduced()
-    model = build(cfg)
-    params = init_params(model.template(), jax.random.PRNGKey(0))
-    eng = Engine(model, params, n_lanes=4, max_len=96, decode_tokens=2)
+    eng = make_replay_engine(4, max_len=96, decode_tokens=2, seed=seed)
     system = profiles.EdgeSystem(n_cameras=4, n_servers=1, n_slots=4,
-                                 seed=1)
+                                 seed=seed)
     ctrl = lbcd.LBCDController(system, v=10.0, p_min=0.6)
     svc = AnalyticsService(ctrl, mode="engine", engine=eng,
                            epoch_duration=2.0)
